@@ -44,6 +44,7 @@ from repro.kernels import ops
 from repro.kernels.ref import qmatmul_ref
 from repro.models.layers import linear
 from repro.models.quantize import _quantize_matrix
+from repro.utils.compile_cache import enable_compile_cache
 
 #: required fused speedup over dequant+einsum at 4-bit on the bench shape
 FUSED_GATE_X = 1.5
@@ -143,6 +144,7 @@ if __name__ == "__main__":
                     help="report the fused speedup without asserting the "
                          f">= {FUSED_GATE_X}x gate")
     args = ap.parse_args()
+    enable_compile_cache()
     rows, _ = run(interpret=args.interpret, gate=not args.no_gate,
                   cli_args=vars(args))
     common.emit(rows)

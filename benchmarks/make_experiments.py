@@ -13,7 +13,7 @@ from pathlib import Path
 
 from repro.configs import SHAPES
 from repro.configs.registry import get_arch
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import TARGET_KIND, device_peaks
 
 ROOT = Path(__file__).resolve().parents[1] / "artifacts"
 
@@ -72,7 +72,7 @@ def roofline_section():
         cfg = get_arch(a)
         shape = SHAPES[s]
         rl = r["roofline"]
-        floor = analytic_memory_floor(cfg, shape, r["kind"], r["devices"]) / HBM_BW * 1e3
+        floor = analytic_memory_floor(cfg, shape, r["kind"], r["devices"]) / device_peaks(TARGET_KIND)["hbm_bw"] * 1e3
         print(f"| {a} | {s} | {rl['compute_ms']:.2f} | {rl['memory_ms']:.2f} |"
               f" {rl['collective_ms']:.2f} | {floor:.2f} | {rl['bottleneck']} |"
               f" {rl['useful_flops_ratio']:.2f} | {rl['roofline_mfu']:.3f} |")

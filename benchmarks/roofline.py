@@ -24,8 +24,9 @@ from pathlib import Path
 
 from repro.configs import SHAPES, QuantConfig
 from repro.configs.registry import get_arch
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, device_peaks
 
+HBM_BW = device_peaks(TARGET_KIND)["hbm_bw"]
 ART = Path(__file__).resolve().parents[1] / "artifacts" / "dryrun"
 SERVE_BITS = QuantConfig(bits=4, dtype="float", block_size=64)
 

@@ -28,6 +28,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.all and args.only:
         ap.error("--all and --only are mutually exclusive")
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (appb_centering, fig2_bitlevel, fig3_blocksize,
                             fig3_datatypes, fig4_proxy, fig_mixed_frontier,
                             kernel_bench, ledger, roofline, serve_bench,

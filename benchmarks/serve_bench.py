@@ -103,6 +103,7 @@ from repro.models.quantize import quantize_params
 from repro.models.sharding import Sharder
 from repro.serving import (KV_LOGIT_TOL, Engine, Server, Telemetry,
                            kv_oracle_logit_gap)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _run_static(eng, reqs, *, num_slots):
@@ -746,6 +747,7 @@ if __name__ == "__main__":
                     help="dump the stats dict as JSON (CI uploads it "
                          "next to the other bench artifacts)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.sla and args.paged:
         raise SystemExit("--sla and --paged are separate benches; "
                          "pick one")

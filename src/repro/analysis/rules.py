@@ -94,7 +94,7 @@ def dotted(node) -> str | None:
 # jit-site discovery (shared by RL001 / RL002)
 
 _JIT_NAMES = {"jax.jit", "jit"}
-_SHMAP_NAMES = {"shard_map_compat", "jax.shard_map", "shard_map"}
+_SHMAP_NAMES = {"jax.shard_map", "shard_map"}
 _PARTIAL_NAMES = {"partial", "functools.partial"}
 
 

@@ -74,7 +74,6 @@ class ArchConfig:
     kv_bits: int = 16                # 16 (bf16 cache) | 8 | 4
     kv_block_size: int = 64
     kv_dtype: str = "float"          # int | float | dynamic (not quantile)
-    kv_use_kernel: bool = False      # Pallas dequant (TPU); False = pure JAX
 
     # Weight-matmul dispatch for QuantizedTensor weights
     # (docs/quantization.md#the-fused-dequant-gemm-serving-path):
@@ -165,8 +164,7 @@ class ArchConfig:
         return count_params(self, active_only=True)
 
     def with_kv_quant(self, bits: int, *, block_size: int | None = None,
-                      dtype: str | None = None,
-                      use_kernel: bool | None = None) -> "ArchConfig":
+                      dtype: str | None = None) -> "ArchConfig":
         """Same arch with a k-bit KV cache. bits=16 restores the bf16 cache."""
         if bits not in (4, 8, 16):
             raise ValueError(f"kv_bits must be 4, 8 or 16, got {bits}")
@@ -181,7 +179,6 @@ class ArchConfig:
             kv_bits=bits,
             kv_block_size=block_size if block_size is not None else self.kv_block_size,
             kv_dtype=kv_dtype,
-            kv_use_kernel=use_kernel if use_kernel is not None else self.kv_use_kernel,
         )
 
     def with_matmul_mode(self, mode: str) -> "ArchConfig":
@@ -232,7 +229,6 @@ class QuantConfig:
     outlier_pct: float = 0.0         # proxy quantization (§3), e.g. 0.02
     quantize_embedding: bool = False
     quantize_lm_head: bool = True
-    use_kernel: bool = False         # Pallas qmatmul (TPU); False = pure-JAX dequant
 
     def describe(self) -> str:
         s = f"{self.dtype}{self.bits}-b{self.block_size}"

@@ -4,8 +4,10 @@ The tensor is viewed as a flat sequence, chunked into blocks of size B;
 each block gets its own 16-bit absmax normalization constant
 (+ optionally a 16-bit mean for distribution centering, App. B).
 Encoding finds the nearest codebook value; because codebooks are sorted
-we use searchsorted over the midpoint boundaries — the paper's "binary
-search" — which is O(log 2^k) and memory-light (no (n, 2^k) broadcast).
+we use searchsorted over the midpoint boundaries.  Its ``compare_all``
+method counts the boundaries below each value in one fused elementwise
+pass, where the default binary search is a loop of per-element gathers
+from the boundary table.
 
 This module is the semantic oracle for kernels/quantize.py and
 kernels/qmatmul ref.py, and the implementation used on CPU.
@@ -57,7 +59,8 @@ def encode(
     scales = jnp.maximum(absmax, 1e-12)
     normed = blocks / scales
     bounds = codebook_boundaries(codebook)
-    codes = jnp.searchsorted(bounds, normed).astype(jnp.uint8)
+    codes = jnp.searchsorted(bounds, normed,
+                             method="compare_all").astype(jnp.uint8)
     return BlockQuantized(
         codes=codes,
         scales=scales[:, 0].astype(scale_dtype),

@@ -55,13 +55,15 @@ class QuantizedTensor:
     centering: bool
     outlier_axis: int = 0
     transposed: bool = False
-    #: structured storage: packed [*B, rows, words_per_row], scales
-    #: [*B, rows, cols//block] — 2-D layouts that (a) shard row-wise under
-    #: GSPMD without the 1-D<->2-D reshapes that force replication
-    #: (EXPERIMENTS.md §Perf iteration 2) and (b) are exactly the fused
-    #: dequant-GEMM kernel operand layout (kernels/qmatmul.py): each row's
-    #: codes are word-aligned, so words_per_row = ceil(cols / cpw) with the
-    #: tail slots of the last word zero for odd bit-widths
+    #: structured storage (K-major): packed [*B, words_per_row, rows],
+    #: scales [*B, cols//block, rows] — each logical row's codes and
+    #: scales run DOWN a column.  2-D layouts that (a) shard by logical
+    #: row under GSPMD without the 1-D<->2-D reshapes that force
+    #: replication and (b) are exactly the fused dequant-GEMM kernel
+    #: operand layout (kernels/qmatmul.py: reduction dim on the TPU's
+    #: sublanes, output dim on its lanes).  Each row's codes are
+    #: word-aligned, so words_per_row = ceil(cols / cpw) with the tail
+    #: slots of the last word zero for odd bit-widths
     structured: bool = False
     #: dtype of the tensor handed to quantize_tensor, as a string (meta
     #: fields must hash); dequantize_params restores it
@@ -175,16 +177,16 @@ def quantize_tensor(
 
 
 def to_structured(qt: QuantizedTensor) -> QuantizedTensor:
-    """Reshape a 2-D-item QT into row-structured storage (see class doc):
-    packed [*B, rows, words_per_row], scales [*B, rows, cols//block].
-    Row-wise GSPMD sharding then works without 1-D<->2-D reshapes (which
-    force involuntary replication — EXPERIMENTS.md §Perf), and the arrays
-    are directly the fused dequant-GEMM kernel operands (kernels/ops.py).
+    """Reshape a 2-D-item QT into K-major structured storage (see class
+    doc): packed [*B, words_per_row, rows], scales [*B, cols//block,
+    rows].  Row-wise GSPMD sharding then works without 1-D<->2-D
+    reshapes (which force involuntary replication), and the arrays are
+    directly the fused dequant-GEMM kernel operands (kernels/ops.py).
 
     Requires cols divisible by the block size (blocks must not straddle
-    rows).  When cols also divide the packing word this is a pure
-    reshape; otherwise (odd bit-widths: 3-bit cpw=10, 5-bit cpw=6,
-    6-bit cpw=5) the flat packing straddles rows and the codes are
+    rows).  When cols also divide the packing word the row-major words
+    are a reshape away; otherwise (odd bit-widths: 3-bit cpw=10, 5-bit
+    cpw=6, 6-bit cpw=5) the flat packing straddles rows and the codes are
     REPACKED row-aligned — each row gets ceil(cols/cpw) words with an
     inert zero tail, the same word-tail convention as core/packing on a
     single row."""
@@ -200,12 +202,16 @@ def to_structured(qt: QuantizedTensor) -> QuantizedTensor:
         packed = packing.pack(codes.reshape(b + (rows, cols)), qt.bits)
     else:
         packed = qt.packed.reshape(b + (rows, cols // cpw))
+
+    def k_major(a):
+        return None if a is None else jnp.swapaxes(
+            a.reshape(b + (rows, -1)), -1, -2)
+
     return dataclasses.replace(
         qt,
-        packed=packed,
-        scales=qt.scales.reshape(b + (rows, cols // qt.block_size)),
-        means=None if qt.means is None
-        else qt.means.reshape(b + (rows, cols // qt.block_size)),
+        packed=k_major(packed),
+        scales=k_major(qt.scales),
+        means=k_major(qt.means),
         structured=True,
     )
 
@@ -220,12 +226,12 @@ def dequantize_tensor(qt: QuantizedTensor, out_dtype=jnp.bfloat16) -> jnp.ndarra
     def one_structured(a):
         rows, cols = quant_shape
         bs = qt.block_size
-        codes = packing.unpack(a["packed"], qt.bits, cols)      # [rows, cols]
+        codes = packing.unpack(a["packed"].T, qt.bits, cols)    # [rows, cols]
         vals = jnp.take(a["cb"], codes.astype(jnp.int32), axis=0)
-        scales = a["scales"].astype(jnp.float32)                # [rows, cols/bs]
+        scales = a["scales"].T.astype(jnp.float32)              # [rows, cols/bs]
         w = vals.reshape(rows, cols // bs, bs) * scales[:, :, None]
         if a["means"] is not None:
-            w = w + a["means"].astype(jnp.float32)[:, :, None]
+            w = w + a["means"].T.astype(jnp.float32)[:, :, None]
         w = w.reshape(rows, cols)
         if a["oidx"] is not None:
             if qt.outlier_axis % 2 == 0:

@@ -33,7 +33,8 @@ Three read paths, one semantics:
     compare-select dequant over the 2**k codebook entries (same no-gather
     trick as kernels/qmatmul.py) + block-scale multiply, one row tile per
     grid step.  Streams k/16 of the bf16 cache bytes from HBM.
-  * ``dequant_rows``        — dispatcher (kernel flag + interpret mode).
+  * ``dequant_rows``        — dispatcher: the kernel on TPU, the oracle
+    elsewhere (kernels/platform.py).
 """
 
 from __future__ import annotations
@@ -45,10 +46,11 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
-from repro.kernels.compat import tpu_compiler_params
 from repro.core.codebooks import codebook_boundaries, make_codebook
+from repro.kernels.platform import kernels_compiled, resolve_interpret
 
 
 class KVQuantSpec(NamedTuple):
@@ -57,7 +59,6 @@ class KVQuantSpec(NamedTuple):
     bits: int
     block_size: int
     dtype_name: str = "float"
-    use_kernel: bool = False
 
 
 def kv_spec(cfg) -> Optional[KVQuantSpec]:
@@ -73,7 +74,6 @@ def kv_spec(cfg) -> Optional[KVQuantSpec]:
         bits=bits,
         block_size=cfg.kv_block_size,
         dtype_name=cfg.kv_dtype,
-        use_kernel=getattr(cfg, "kv_use_kernel", False),
     )
 
 
@@ -117,7 +117,9 @@ def encode_rows(x: jnp.ndarray, spec: KVQuantSpec):
     scales = jnp.maximum(absmax, 1e-12)
     normed = xb / scales[..., None]
     bounds = codebook_boundaries(kv_codebook(spec))
-    codes = jnp.searchsorted(bounds, normed).astype(jnp.uint32)
+    # gather-free, as core/blockwise.encode
+    codes = jnp.searchsorted(bounds, normed,
+                             method="compare_all").astype(jnp.uint32)
     packed = packing.pack(codes.reshape(x.shape[:-1] + (feat,)), spec.bits)
     return packed, scales.astype(jnp.bfloat16)
 
@@ -147,7 +149,9 @@ def _dequant_kernel(p_ref, s_ref, cb_ref, o_ref, *, bits, bs, feat, dtype_name):
     codes = codes.reshape(words.shape[0], feat)
     if dtype_name == "int":
         half = float(2 ** (bits - 1) - 1)
-        vals = jnp.clip(codes.astype(jnp.float32) - half, -half, half) / half
+        # via int32: Mosaic has no uint32 -> f32 cast (codes < 2**bits)
+        vals = jnp.clip(codes.astype(jnp.int32).astype(jnp.float32) - half,
+                        -half, half) / half
     else:
         vals = jnp.zeros(codes.shape, jnp.float32)
         for j in range(2**bits):                         # vectorized selects
@@ -157,10 +161,11 @@ def _dequant_kernel(p_ref, s_ref, cb_ref, o_ref, *, bits, bs, feat, dtype_name):
 
 
 def dequant_rows_pallas(packed, scales, spec: KVQuantSpec, feat: int, *,
-                        tile_rows: int = 256, interpret: bool = False,
+                        tile_rows: int = 128, interpret: bool | None = None,
                         out_dtype=jnp.bfloat16):
     """Pallas dequant of flattened rows: packed [R, W], scales [R, NB] ->
-    [R, feat].  Rows are padded up to a tile multiple and sliced back."""
+    [R, feat].  Rows are padded up to a tile multiple and sliced back.
+    ``interpret`` None: compiled on TPU, interpreted elsewhere."""
     bs, n_blocks, n_words = kv_layout(spec, feat)
     R = packed.shape[0]
     tr = min(tile_rows, max(R, 1))
@@ -186,26 +191,25 @@ def dequant_rows_pallas(packed, scales, spec: KVQuantSpec, feat: int, *,
         ],
         out_specs=pl.BlockSpec((tr, feat), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * tr, feat), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed, scales, cb2)
     return out[:R]
 
 
 def dequant_rows(packed, scales, spec: KVQuantSpec, feat: int, *,
-                 interpret: bool = False, out_dtype=jnp.bfloat16):
-    """Dequantize [..., W]/[..., NB] leaves to [..., feat] values,
-    dispatching to the Pallas kernel when the spec asks for it (TPU, or
-    interpret mode for validation) and the jnp oracle otherwise."""
-    if not spec.use_kernel and not interpret:
+                 out_dtype=jnp.bfloat16):
+    """Dequantize [..., W]/[..., NB] leaves to [..., feat] values: the
+    compiled Pallas kernel on TPU, the jnp oracle elsewhere."""
+    if not kernels_compiled():
         return dequant_rows_ref(packed, scales, spec, feat, out_dtype=out_dtype)
     lead = packed.shape[:-1]
     flat = dequant_rows_pallas(
         packed.reshape((-1, packed.shape[-1])),
         scales.reshape((-1, scales.shape[-1])),
-        spec, feat, interpret=interpret, out_dtype=out_dtype,
+        spec, feat, out_dtype=out_dtype,
     )
     return flat.reshape(lead + (feat,))
 
@@ -232,14 +236,14 @@ def gather_pages(leaf: jnp.ndarray, page_map: jnp.ndarray) -> jnp.ndarray:
 
 
 def dequant_pages(packed, scales, page_map, spec: KVQuantSpec, feat: int, *,
-                  interpret: bool = False, out_dtype=jnp.bfloat16):
+                  out_dtype=jnp.bfloat16):
     """Dequantize a paged packed cache through a page-index vector:
     packed [n_pages, ps, W] + scales [n_pages, ps, NB] gathered via
     ``page_map`` [B, P] -> dense [B, P*ps, feat].  Bitwise equal to
     gathering a pre-dequantized cache because dequant is row-local."""
     return dequant_rows(
         gather_pages(packed, page_map), gather_pages(scales, page_map),
-        spec, feat, interpret=interpret, out_dtype=out_dtype,
+        spec, feat, out_dtype=out_dtype,
     )
 
 

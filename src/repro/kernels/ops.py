@@ -7,8 +7,8 @@ The fused dequant-GEMM has three execution backends
 (docs/quantization.md#the-fused-dequant-gemm-serving-path):
 
 * ``pallas``  — kernels/qmatmul.py, the real TPU kernel (interpret mode
-  on CPU for parity tests only; interpret is orders of magnitude slower
-  than jnp);
+  off TPU, for parity tests only; interpret is orders of magnitude
+  slower than jnp);
 * ``jnp``     — :func:`qmatmul_fused_jnp`, a jit-friendly path with the
   kernel's VALUES (arithmetic dequant for ``int`` codebooks, codebook
   lookup for LUTs — XLA CPU vectorizes small-table gathers fine; the
@@ -20,9 +20,12 @@ The fused dequant-GEMM has three execution backends
   naive dequant+einsum slow);
 * ``oracle``  — kernels/ref.py, the semantic ground truth.
 
-``fused_backend()`` picks per jax backend; the model layer
-(models/layers.linear) routes QuantizedTensor matmuls here when
-``cfg.matmul_mode`` resolves to fused.
+``fused_backend()`` picks per platform (kernels/platform.py); the model
+layer (models/layers.linear) routes QuantizedTensor matmuls here when
+``cfg.matmul_mode`` resolves to fused.  On TPU a matrix the kernel cannot
+tile (``pallas_fusable``: odd bit-widths, blocks narrower than eight
+words, dims off the (8, 128) grid) is not fused-eligible and takes the
+dequant path instead.
 """
 
 from __future__ import annotations
@@ -41,12 +44,8 @@ from repro.core.codebooks import make_codebook
 from repro.core.qtensor import QuantizedTensor
 from repro.kernels import qmatmul as qk
 from repro.kernels import quantize as quantk
-from repro.kernels.compat import shard_map_compat
-from repro.kernels.ref import QMatmulOperand, qmatmul_ref, quantize_blocks_ref
-
-
-def _lcm(a, b):
-    return a * b // math.gcd(a, b)
+from repro.kernels.platform import kernels_compiled, resolve_interpret
+from repro.kernels.ref import QMatmulOperand, qmatmul_ref
 
 
 def prepare_operand(
@@ -57,13 +56,14 @@ def prepare_operand(
     block_size: int = 64,
     exponent_bits=None,
 ) -> QMatmulOperand:
-    """Quantize a dense weight [K, N] into kernel layout (blocks along K).
+    """Quantize a dense weight [K, N] into kernel layout (blocks along K,
+    K-major storage: packed [ceil(Kb / cpw), N], scales [Kb // B, N]).
 
     K need not divide the block size or the packing word: the reduction
     dim is zero-padded to block alignment (zeros quantize to the exact-0
     code for the static codebooks, and the matmul wrappers zero-pad the
-    activations to match), and each row's codes pack word-aligned with an
-    inert tail for odd bit-widths."""
+    activations to match), and each column's codes pack word-aligned with
+    an inert tail for odd bit-widths."""
     K, N = w.shape
     # data-dependent (quantile) codebooks must see the REAL weights:
     # build before padding so artificial zeros don't skew the bins
@@ -73,35 +73,79 @@ def prepare_operand(
         w = jnp.pad(w, ((0, Kb - K), (0, 0)))
     q = blockwise.encode(w.T, cb, block_size)  # blocks run along K per column
     codes = q.codes.reshape(N, Kb)
-    packed = packing.pack(codes, bits)         # word-aligned per row
-    scales = q.scales.reshape(N, Kb // block_size)
+    packed = packing.pack(codes, bits).T       # word-aligned per column
+    scales = q.scales.reshape(N, Kb // block_size).T
     return QMatmulOperand(
         packed=packed, scales=scales, codebook=cb,
         bits=bits, block_size=block_size, k_dim=Kb, dtype_name=dtype,
     )
 
 
+#: Pallas tile caps: output columns, reduction rows and activation rows
+_BN_MAX, _BK_MAX, _BM_MAX = 512, 2048, 256
+
+
+def _k_unit(bits: int, block_size: int) -> int:
+    """Smallest K tile whose x block (lanes), word block and scale block
+    (sublanes) all sit on the TPU's (8, 128) tiling."""
+    cpw = 32 // bits
+    return math.lcm(128, 8 * cpw, 8 * block_size)
+
+
+def _tile(n: int, unit: int, cap: int) -> int:
+    """Largest multiple of `unit` dividing `n`, at most `cap`; `n` itself
+    (a whole-extent block, always legal) when there is none."""
+    t = min(cap, n) // unit * unit
+    while t >= unit:
+        if n % t == 0:
+            return t
+        t -= unit
+    return n
+
+
+def pallas_fusable(bits: int, block_size: int, n: int, k: int) -> bool:
+    """Does the compiled Pallas kernel take a [K=k, N=n] operand at this
+    width?  The rule: codes fill whole words (bits divides 32), a block
+    spans whole words (a scale row expands by a sublane broadcast), N
+    tiles by 128 lanes and K by ``_k_unit``.  Anything else — odd
+    bit-widths among them — takes the dequant-einsum path on TPU."""
+    if 32 % bits:
+        return False
+    return (block_size % (32 // bits) == 0 and n % 128 == 0
+            and k % _k_unit(bits, block_size) == 0)
+
+
 def qt_fused_eligible(qt) -> bool:
     """Can this QuantizedTensor be viewed as a fused-GEMM operand?
 
-    Requires row-structured 2-D storage with no leading batch dims (a
-    scan has already sliced the layer axis), no centering means and no
-    proxy outlier rows — the kernel streams packed codes + scales only.
-    Ineligible QTs take the dequant-einsum path per matrix."""
-    return (
+    Requires structured 2-D storage with no leading batch dims (a scan
+    has already sliced the layer axis), no centering means and no proxy
+    outlier rows — the kernel streams packed codes + scales only.  Where
+    the fused backend is the Pallas kernel the matrix (its local shard
+    inside a TP scope) must also be ``pallas_fusable``.  Ineligible QTs
+    take the dequant-einsum path per matrix."""
+    if not (
         isinstance(qt, QuantizedTensor)
         and qt.structured
         and len(qt.quant_shape) == 2
         and qt.packed.ndim == 2
         and qt.means is None
         and qt.outlier_idx is None
-    )
+    ):
+        return False
+    if fused_backend() != "pallas":
+        return True
+    n, k = qt.quant_shape
+    tp = current_tp_scope()
+    if tp is not None and tp.tp_size > 1 and n % tp.tp_size == 0:
+        n //= tp.tp_size
+    return pallas_fusable(qt.bits, qt.block_size, n, k)
 
 
 def operand_from_qtensor(qt: QuantizedTensor) -> QMatmulOperand:
     """View a 2-D QuantizedTensor storing [N, K] (transposed weights, or
     lm_head/embed which are natively (out, in)) as kernel operands.
-    Structured QTs are already in kernel layout — any bit-width, row
+    Structured QTs are already in kernel layout — any bit-width, column
     word tails included; flat ones are reshaped when aligned."""
     assert len(qt.quant_shape) == 2, "need [N, K] storage"
     N, K = qt.quant_shape
@@ -112,8 +156,8 @@ def operand_from_qtensor(qt: QuantizedTensor) -> QMatmulOperand:
     else:
         assert K % cpw == 0, "flat storage must align to the packing word"
         assert K % qt.block_size == 0, "flat storage must align to blocks"
-        packed = qt.packed.reshape(N, K // cpw)
-        scales = qt.scales.reshape(N, K // qt.block_size)
+        packed = qt.packed.reshape(N, K // cpw).T
+        scales = qt.scales.reshape(N, K // qt.block_size).T
     return QMatmulOperand(
         packed=packed,
         scales=scales,
@@ -128,7 +172,7 @@ def operand_from_qtensor(qt: QuantizedTensor) -> QMatmulOperand:
 def fused_backend() -> str:
     """Default fused-GEMM backend for this process: the Pallas kernel on
     TPU, the gather-free jnp path everywhere else."""
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    return "pallas" if kernels_compiled() else "jnp"
 
 
 # --------------------------------------------------------------------------
@@ -204,9 +248,9 @@ def tp_column_parallel_einsum(x, wt, tp: TPScope):
     def local(x2, wt_local):
         return jnp.einsum("mk,nk->mn", x2, wt_local)
 
-    y = shard_map_compat(
-        local, tp.mesh, in_specs=(P(rows), P(tp.axis)),
-        out_specs=P(rows, tp.axis),
+    y = jax.shard_map(
+        local, mesh=tp.mesh, in_specs=(P(rows), P(tp.axis)),
+        out_specs=P(rows, tp.axis), check_vma=False,
     )(x2, wt)
     return y.reshape(lead + (y.shape[-1],))
 
@@ -214,8 +258,8 @@ def tp_column_parallel_einsum(x, wt, tp: TPScope):
 def _fused_matmul_tp(x, op: QMatmulOperand, *, backend, interpret,
                      tp: TPScope):
     """Column-parallel fused dequant-GEMM: activation rows sharded over
-    the data axes (when they divide), operand rows sharded over the TP
-    axis, output sharded (rows, columns) accordingly."""
+    the data axes (when they divide), operand columns sharded over the
+    TP axis, output sharded (rows, columns) accordingly."""
     lead = x.shape[:-1]
     x2 = _pad_x_to_k(x.reshape(-1, x.shape[-1]), op.k_dim)
     rows = _row_part(tp, x2.shape[0])
@@ -229,10 +273,10 @@ def _fused_matmul_tp(x, op: QMatmulOperand, *, backend, interpret,
         return _fused_matmul_local(x2, lop, backend=backend,
                                    interpret=interpret)
 
-    y = shard_map_compat(
-        local, tp.mesh,
-        in_specs=(P(rows), P(tp.axis), P(tp.axis), P()),
-        out_specs=P(rows, tp.axis),
+    y = jax.shard_map(
+        local, mesh=tp.mesh,
+        in_specs=(P(rows), P(None, tp.axis), P(None, tp.axis), P()),
+        out_specs=P(rows, tp.axis), check_vma=False,
     )(x2, op.packed, op.scales, op.codebook)
     return y.reshape(lead + (y.shape[-1],))
 
@@ -240,21 +284,20 @@ def _fused_matmul_tp(x, op: QMatmulOperand, *, backend, interpret,
 def qmatmul_fused_jnp(x2: jnp.ndarray, op: QMatmulOperand) -> jnp.ndarray:
     """Fused path without Pallas: x2 [M, k_dim] @ W -> [M, N] in x2.dtype.
 
-    Dequantizes straight into [K, N] layout (one cheap uint32 transpose of
-    the packed words, never a [N, K] float transpose), applies scales via
-    a blocked reshape, fences with an optimization barrier, and runs a
-    single f32 GEMM.  Mirrors kernel semantics: values and scales agree
-    with the oracle bit-for-bit; only f32 accumulation order differs."""
+    Dequantizes straight from the K-major storage into [K, N] layout
+    (never a [N, K] float transpose), applies scales via a blocked
+    reshape, fences with an optimization barrier, and runs a single f32
+    GEMM.  Mirrors kernel semantics: values and scales agree with the
+    oracle bit-for-bit; only f32 accumulation order differs."""
     K = op.k_dim
-    N = op.packed.shape[0]
+    N = op.packed.shape[1]
     bits, bs = op.bits, op.block_size
     cpw = 32 // bits
     assert K % bs == 0, (K, bs)
 
     shifts = jnp.arange(cpw, dtype=jnp.uint32) * bits
     mask = jnp.uint32((1 << bits) - 1)
-    p_t = op.packed.T                                   # [W, N] uint32
-    c = ((p_t[:, None, :] >> shifts[None, :, None]) & mask)
+    c = ((op.packed[:, None, :] >> shifts[None, :, None]) & mask)
     c = c.reshape(-1, N)[:K]                            # [K, N] codes
     if op.dtype_name == "int":
         half = float(2 ** (bits - 1) - 1)
@@ -262,8 +305,8 @@ def qmatmul_fused_jnp(x2: jnp.ndarray, op: QMatmulOperand) -> jnp.ndarray:
     else:
         vals = jnp.take(op.codebook.astype(jnp.float32),
                         c.astype(jnp.int32), axis=0)
-    s_t = op.scales.astype(jnp.float32).T               # [K // bs, N]
-    wt = (vals.reshape(K // bs, bs, N) * s_t[:, None, :]).reshape(K, N)
+    s = op.scales.astype(jnp.float32)                   # [K // bs, N]
+    wt = (vals.reshape(K // bs, bs, N) * s[:, None, :]).reshape(K, N)
     # round the weight tile to the activation dtype — exactly the
     # transient dequantize_tensor(out_dtype=x.dtype) produces — so the
     # fused and dequant_einsum paths multiply IDENTICAL weight values
@@ -288,48 +331,44 @@ def qmatmul(
     x: jnp.ndarray,
     op: QMatmulOperand,
     *,
-    use_kernel: bool = True,
-    interpret: bool = True,
-    bm: int = 128,
-    bn: int = 128,
+    interpret: bool | None = None,
 ):
     """y = x @ W via the Pallas kernel, x [..., K<=k_dim] -> [..., N].
-    Pads M/N/K to tile alignment (including odd-bit word tails: the
-    word-aligned row packing makes zero-padding the word axis exactly
-    equivalent to packing zero-padded codes).  use_kernel=False runs the
-    oracle."""
+
+    Picks (8, 128)-aligned tiles (``_tile``; a dim with no aligned
+    divisor becomes one whole-extent block), pads M and pads K to the
+    word/block alignment (including odd-bit word tails: the word-aligned
+    column packing makes zero-padding the word axis exactly equivalent to
+    packing zero-padded codes), and permutes x into the kernel's
+    plane-major column order.  ``interpret`` None: compiled on TPU,
+    interpreted elsewhere."""
     lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
-    if not use_kernel:
-        y = qmatmul_ref(x2, op)
-        return y.reshape(lead + (y.shape[-1],))
-
-    x2 = _pad_x_to_k(x2, op.k_dim)
+    x2 = _pad_x_to_k(x.reshape(-1, x.shape[-1]), op.k_dim)
     M, K = x2.shape
-    N = op.packed.shape[0]
-    cpw = 32 // op.bits
+    N = op.packed.shape[1]
+    bits, bs = op.bits, op.block_size
+    cpw = 32 // bits
 
-    bk = _lcm(cpw, op.block_size)
-    Kp = -(-K // bk) * bk
-    bm_eff = min(bm, max(8, 8 * (-(-M // 8))))
-    Mp = -(-M // bm_eff) * bm_eff
-    bn_eff = min(bn, N)
-    Np = -(-N // bn_eff) * bn_eff
+    align = math.lcm(cpw, bs)
+    Kp = -(-K // align) * align
+    bk = _tile(Kp, _k_unit(bits, bs), _BK_MAX)
+    bn = _tile(N, 128, _BN_MAX)
+    bm = min(_BM_MAX, 8 * (-(-M // 8)))
+    Mp = -(-M // bm) * bm
 
     xp = jnp.pad(x2, ((0, Mp - M), (0, Kp - K)))
-    packed = jnp.pad(
-        op.packed, ((0, Np - N), (0, Kp // cpw - op.packed.shape[1]))
-    )
-    scales = jnp.pad(
-        op.scales, ((0, Np - N), (0, Kp // op.block_size - op.scales.shape[1]))
-    )
+    packed, scales = op.packed, op.scales
+    if packed.shape[0] != Kp // cpw:
+        packed = jnp.pad(packed, ((0, Kp // cpw - packed.shape[0]), (0, 0)))
+    if scales.shape[0] != Kp // bs:
+        scales = jnp.pad(scales, ((0, Kp // bs - scales.shape[0]), (0, 0)))
 
     y = qk.qmatmul_pallas(
-        xp, packed, scales, op.codebook,
-        bits=op.bits, block_size=op.block_size, dtype_name=op.dtype_name,
-        bm=bm_eff, bn=bn_eff, bk=bk, interpret=interpret,
+        qk.permute_x(xp, bits, bk), packed, scales, op.codebook,
+        bits=bits, block_size=bs, dtype_name=op.dtype_name,
+        bm=bm, bn=bn, bk=bk, interpret=resolve_interpret(interpret),
     )
-    return y[:M, :N].reshape(lead + (N,))
+    return y[:M].reshape(lead + (N,))
 
 
 def _fused_matmul_local(
@@ -346,9 +385,7 @@ def _fused_matmul_local(
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        return qmatmul(x, op, use_kernel=True, interpret=interpret)
+        return qmatmul(x, op, interpret=interpret)
     x2 = _pad_x_to_k(x2, op.k_dim)
     if backend == "jnp":
         y = qmatmul_fused_jnp(x2, op)
@@ -369,17 +406,17 @@ def fused_matmul(
     """Backend-dispatched fused dequant-GEMM: x [..., K<=k_dim] -> [..., N].
 
     backend: "pallas" | "jnp" | "oracle" (None -> fused_backend()).
-    interpret only applies to the pallas backend (None -> interpret off
-    TPU, i.e. CPU parity-test mode).
+    interpret only applies to the pallas backend (None -> compiled on
+    TPU, interpreted elsewhere).
 
     Inside a :func:`tp_dispatch_scope` (models/sharding.Sharder.tp_scope)
-    the matmul runs column-parallel: operands whose output-row count
+    the matmul runs column-parallel: operands whose output-column count
     divides the TP degree keep packed/scales sharded on `model` and hit
     the per-shard body inside a shard_map; others run the single-shard
     body and let GSPMD place them."""
     tp = current_tp_scope()
     if tp is not None and op.packed.ndim == 2:
-        if tp.tp_size > 1 and op.packed.shape[0] % tp.tp_size == 0:
+        if tp.tp_size > 1 and op.packed.shape[1] % tp.tp_size == 0:
             return _fused_matmul_tp(x, op, backend=backend,
                                     interpret=interpret, tp=tp)
     return _fused_matmul_local(x, op, backend=backend, interpret=interpret)
@@ -390,22 +427,21 @@ def quantize_blocks(
     codebook: jnp.ndarray,
     block_size: int,
     *,
-    use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
-    """Blockwise encode of a flat tensor -> (codes [n_blocks, B], scales)."""
+    """Blockwise encode of a flat tensor -> (codes [n_blocks, B], scales)
+    through the Pallas encode kernel (oracle: ref.quantize_blocks_ref)."""
     flat = jnp.ravel(x).astype(jnp.float32)
     n_blocks = -(-flat.shape[0] // block_size)
     pad = n_blocks * block_size - flat.shape[0]
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
     xb = flat.reshape(n_blocks, block_size)
-    if not use_kernel:
-        return quantize_blocks_ref(xb, codebook)
     tile = 256
     while n_blocks % tile:
         tile //= 2
     codes, scales = quantk.quantize_blocks_pallas(
-        xb, codebook, tile_blocks=max(tile, 1), interpret=interpret
+        xb, codebook, tile_blocks=max(tile, 1),
+        interpret=resolve_interpret(interpret),
     )
     return codes, scales[:, 0]

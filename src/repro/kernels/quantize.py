@@ -14,8 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _quantize_kernel(x_ref, b_ref, codes_ref, scales_ref, *, n_bounds):
@@ -61,7 +60,7 @@ def quantize_blocks_pallas(
             jax.ShapeDtypeStruct((n_blocks, B), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -20,17 +20,19 @@ class QMatmulOperand(NamedTuple):
     """Kernel-layout quantized weight for y = x @ W, W logical [K, N].
 
     Blocks run along the reduction dim K (per output column), matching the
-    transposed QuantizedTensor storage (models/quantize.py).  Rows are
-    packed word-aligned: for odd bit-widths the last word of each row
-    carries an inert zero tail, so ``packed.shape[1] == ceil(K / cpw)``
-    (== K // cpw exactly when cpw divides K).  ``k_dim`` is the stored
+    structured QuantizedTensor storage (models/quantize.py).  Storage is
+    K-major — K down the rows, N across the columns, the TPU tiling of
+    the Pallas kernel.  Each column packs word-aligned: for odd
+    bit-widths its last word carries an inert zero tail, so
+    ``packed.shape[0] == ceil(K / cpw)`` (== K // cpw exactly when cpw
+    divides K).  ``k_dim`` is the stored
     (block-aligned) K; activations with fewer columns are zero-padded by
     the callers — the padded region dequantizes against real codes but
     multiplies zero activations, so it cannot contribute.
     """
 
-    packed: jnp.ndarray    # uint32 [N, ceil(K / cpw)]
-    scales: jnp.ndarray    # bf16   [N, K // block]
+    packed: jnp.ndarray    # uint32 [ceil(K / cpw), N]
+    scales: jnp.ndarray    # bf16   [K // block, N]
     codebook: jnp.ndarray  # f32    [2**bits]
     bits: int
     block_size: int
@@ -40,10 +42,10 @@ class QMatmulOperand(NamedTuple):
 
 def dequantize_operand(op: QMatmulOperand, out_dtype=jnp.float32) -> jnp.ndarray:
     """Full dequantized W^T [N, K]."""
-    codes = packing.unpack(op.packed, op.bits, op.k_dim)  # [N, K]
+    codes = packing.unpack(op.packed.T, op.bits, op.k_dim)  # [N, K]
     vals = jnp.take(op.codebook, codes.astype(jnp.int32), axis=0)
     scales = jnp.repeat(
-        op.scales.astype(jnp.float32), op.block_size, axis=1
+        op.scales.T.astype(jnp.float32), op.block_size, axis=1
     )[:, : op.k_dim]
     return (vals * scales).astype(out_dtype)
 

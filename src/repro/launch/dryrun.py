@@ -128,9 +128,10 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, save: bool = True,
 
 def _roofline(record: dict, n_chips: int) -> dict:
     c = record["hlo_cost"]
-    compute_s = c["flops_per_device"] / mesh_mod.PEAK_FLOPS_BF16
-    memory_s = c["hbm_bytes_per_device"] / mesh_mod.HBM_BW
-    collective_s = c["collective_bytes_per_device"] / mesh_mod.ICI_BW
+    peaks = mesh_mod.device_peaks(mesh_mod.TARGET_KIND)
+    compute_s = c["flops_per_device"] / peaks["flops_bf16"]
+    memory_s = c["hbm_bytes_per_device"] / peaks["hbm_bw"]
+    collective_s = c["collective_bytes_per_device"] / peaks["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     useful = record["model_flops_global"] / max(
@@ -138,7 +139,7 @@ def _roofline(record: dict, n_chips: int) -> dict:
     )
     step_s = max(terms.values())
     mfu = record["model_flops_global"] / (
-        n_chips * mesh_mod.PEAK_FLOPS_BF16 * step_s
+        n_chips * peaks["flops_bf16"] * step_s
     ) if step_s > 0 else 0.0
     return {
         "roofline": {

@@ -79,7 +79,13 @@ from repro.configs import QuantConfig
 from repro.configs.registry import get_arch
 from repro.data import synthetic
 from repro.models import lm
-from repro.models.quantize import bits_report, quantize_params, quantize_tree
+from repro.launch.mesh import make_mesh
+from repro.models.quantize import (
+    bits_report,
+    init_quantized_params,
+    quantize_params,
+    quantize_tree,
+)
 from repro.models.sharding import Sharder
 from repro.precision import PrecisionPlan
 from repro.serving import (
@@ -92,6 +98,7 @@ from repro.serving import (
 )
 from repro.serving.telemetry import record_quant_health
 from repro.train import step as step_mod
+from repro.utils.compile_cache import enable_compile_cache
 
 _STATIC_ONLY = ("batch", "prompt_len")
 _CONTINUOUS_ONLY = ("num_slots", "num_requests", "rate", "prefill_chunk",
@@ -125,7 +132,19 @@ def parse_mesh(spec: str | None):
             f"{jax.device_count()} (CPU: export XLA_FLAGS="
             f"--xla_force_host_platform_device_count={d * m})"
         )
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
+
+
+def parse_peaks(spec: str) -> tuple[float, float]:
+    """'FLOPS,BYTES_PER_S' -> (peak FLOP/s, HBM bytes/s), both > 0."""
+    try:
+        flops, bw = (float(p) for p in spec.split(","))
+    except ValueError:
+        raise SystemExit(f"--peaks wants FLOPS,BYTES_PER_S (e.g. "
+                         f"197e12,819e9), got {spec!r}") from None
+    if not (flops > 0 and bw > 0):
+        raise SystemExit(f"--peaks must be positive, got {spec!r}")
+    return flops, bw
 
 
 def validate_flags(args) -> None:
@@ -202,6 +221,11 @@ def validate_flags(args) -> None:
             "sink is configured — add --metrics-out (and/or --trace-out) "
             "or drop --profile"
         )
+    if args.peaks is not None:
+        if not args.profile:
+            raise SystemExit("--peaks sets the roofline the step profiler "
+                             "divides by; it needs --profile")
+        parse_peaks(args.peaks)
     if args.prefill_chunk is not None and args.prefill_chunk < 1:
         raise SystemExit("--prefill-chunk wants a positive chunk length, "
                          f"got {args.prefill_chunk}")
@@ -255,6 +279,8 @@ def build_argparser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--ckpt-dir", default=None, help="default: random init")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the random init (no --ckpt-dir)")
     # quantization flags default to None so --plan / --dtype fp16 can
     # reject explicit conflicts loudly instead of silently ignoring them
     ap.add_argument("--bits", type=int, default=None, help="default: 4")
@@ -358,6 +384,11 @@ def build_argparser() -> argparse.ArgumentParser:
                          "measured step times against the roofline, print "
                          "a per-program summary and export profile_* "
                          "gauges (needs a telemetry sink)")
+    ap.add_argument("--peaks", default=None, metavar="FLOPS,BYTES_PER_S",
+                    help="roofline peaks for --profile on a device that "
+                         "launch/mesh.DEVICE_PEAKS does not list (default: "
+                         "the table entry of this device; an unlisted "
+                         "device is an error)")
     return ap
 
 
@@ -392,13 +423,19 @@ def _finish_telemetry(tel, args) -> None:
 def main(argv=None):
     args = build_argparser().parse_args(argv)
     validate_flags(args)
+    enable_compile_cache()
     mesh = parse_mesh(args.mesh)
     telemetry = NOOP
     if args.metrics_out is not None or args.trace_out is not None:
+        profiler = None
+        if args.profile:
+            flops, bw = (parse_peaks(args.peaks) if args.peaks is not None
+                         else (None, None))
+            profiler = StepProfiler(peak_flops=flops, hbm_bw=bw)
         telemetry = Telemetry(
             kv_probe_every=args.kv_probe_every
             if args.kv_probe_every is not None else 0,
-            profiler=StepProfiler() if args.profile else None)
+            profiler=profiler)
 
     cfg = get_arch(args.arch).with_matmul_mode(args.matmul_mode)
     if args.matmul_mode != "auto":
@@ -415,10 +452,28 @@ def main(argv=None):
         # the actual seq-shard degree depends on the batch/slot split;
         # the continuous path prints the measured per-device pool bytes
         print(f"mesh: {dict(mesh.shape)}")
+    qcfg = None
+    if args.plan is None and args.dtype != "fp16":
+        qcfg = QuantConfig(bits=args.bits if args.bits is not None else 4,
+                           dtype=args.dtype if args.dtype is not None else "float",
+                           block_size=args.block_size
+                           if args.block_size is not None else 64,
+                           outlier_pct=args.outlier_pct
+                           if args.outlier_pct is not None else 0.0)
+    key = jax.random.PRNGKey(args.seed)
     if args.ckpt_dir:
         params = load_params(cfg, args.ckpt_dir)
+    elif qcfg is not None and qcfg.outlier_pct == 0:
+        # random weights straight into packed form, one layer at a time:
+        # the dense f32 tree of a full-width model need never exist
+        params = init_quantized_params(key, cfg, qcfg)
+        rep = bits_report(params)
+        print(f"initialised {qcfg.describe()} layer by layer: "
+              f"{rep['avg_bits_per_param']:.2f} bits/param, "
+              f"{rep['total_bits_ideal']/8e9:.3f} GB ideal")
+        qcfg = None  # already quantized
     else:
-        params = lm.init_params(jax.random.PRNGKey(0), cfg)
+        params = lm.init_params(key, cfg)
 
     if args.plan is not None:
         plan = PrecisionPlan.load(args.plan)
@@ -430,13 +485,7 @@ def main(argv=None):
         print(f"quantized per plan {args.plan} ({plan.describe()}): "
               f"{rep['avg_bits_per_param']:.2f} bits/param, "
               f"{rep['total_bits_ideal']/8e9:.3f} GB ideal")
-    elif args.dtype != "fp16":
-        qcfg = QuantConfig(bits=args.bits if args.bits is not None else 4,
-                           dtype=args.dtype if args.dtype is not None else "float",
-                           block_size=args.block_size
-                           if args.block_size is not None else 64,
-                           outlier_pct=args.outlier_pct
-                           if args.outlier_pct is not None else 0.0)
+    elif qcfg is not None:
         record_quant_health(telemetry, params, cfg, qcfg=qcfg)
         params = quantize_params(params, qcfg, cfg)
         rep = bits_report(params)

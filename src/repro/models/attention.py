@@ -517,8 +517,8 @@ def combine_partials(m, l, pv, axis_name: str | None):
 
 def dequant_cache_kv(cache: dict, kvq, n_kv_heads: int, head_dim: int):
     """Materialize bf16 k/v [B, S_c, K, Dh] from a packed cache — the
-    dequant-attention read path (Pallas kernel when kvq.use_kernel, jnp
-    oracle otherwise; kernels/kv_dequant.py)."""
+    dequant-attention read path (Pallas kernel on TPU, jnp oracle
+    elsewhere; kernels/kv_dequant.py)."""
     feat = n_kv_heads * head_dim
     shape = cache["k_packed"].shape[:2] + (n_kv_heads, head_dim)
     k = kv_dequant.dequant_rows(

@@ -22,19 +22,27 @@ NO_CONSTRAIN = lambda x, kind: x
 # params
 # --------------------------------------------------------------------------
 
+def init_embed(key, cfg) -> jnp.ndarray:
+    return jax.random.normal(key, (cfg.vocab_size, cfg.d_model),
+                             jnp.float32) * 0.02
+
+
+def init_lm_head(key, cfg) -> jnp.ndarray:
+    return jax.random.normal(key, (cfg.vocab_size, cfg.d_model),
+                             jnp.float32) * cfg.d_model**-0.5
+
+
 def init_params(key, cfg) -> dict:
+    """Random f32 params.  Keys: split(key, 3) -> embed, stack, lm_head
+    (models/quantize.init_quantized_params streams the same keys)."""
     ks = jax.random.split(key, 3)
     p = {
-        "embed": jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model), jnp.float32)
-        * 0.02,
+        "embed": init_embed(ks[0], cfg),
         "stack": blocks.init_stack(ks[1], cfg),
         "final_norm": init_norm(cfg.d_model, cfg.norm_type),
     }
     if not cfg.tie_embeddings:
-        p["lm_head"] = (
-            jax.random.normal(ks[2], (cfg.vocab_size, cfg.d_model), jnp.float32)
-            * cfg.d_model**-0.5
-        )
+        p["lm_head"] = init_lm_head(ks[2], cfg)
     return p
 
 

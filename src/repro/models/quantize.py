@@ -242,6 +242,105 @@ def quantize_params(params, qcfg: QuantConfig, cfg):
     return quantize_tree(params, cfg, qcfg=qcfg)
 
 
+def init_quantized_params(key, cfg, qcfg: QuantConfig, *,
+                          chunk_bytes: int = 256 << 20) -> dict:
+    """``quantize_params(lm.init_params(key, cfg), qcfg, cfg)`` without
+    ever holding the dense tree: the same keys, codes and scales, built
+    one layer at a time, so the device peaks at the packed model plus one
+    dense layer (a full-width model whose f32 tree outgrows the chip).
+
+    Each layer of the period scan is initialised and quantized by one
+    jitted call (a leading axis of 1 makes it the stacked unit's single
+    item) and written into the preallocated stacked leaves in place.
+    lm_head (and a quantized embedding) is drawn whole in f32 and
+    quantized in row chunks of at most `chunk_bytes`: blocks never cross
+    rows, so the codes and scales are those of the whole matrix.  A dense
+    embedding is stored in bf16 — the dtype the forward reads it in
+    (lm.embed_inputs).
+
+    Proxy quantization (``outlier_pct > 0``) needs every layer's producer
+    statistics at once and is rejected: build the dense tree for it."""
+    from repro.models import blocks, lm
+    from repro.models.layers import init_norm
+
+    if qcfg.outlier_pct > 0:
+        raise ValueError("init_quantized_params cannot stream proxy "
+                         "quantization (outlier_pct > 0): the residual "
+                         "outlier set spans every layer")
+    ks = jax.random.split(key, 3)
+    n_periods = cfg.n_layers // cfg.scan_period()
+    put = jax.jit(
+        lambda stacked, one, i: jax.tree.map(
+            lambda s, o: jax.lax.dynamic_update_slice_in_dim(s, o, i, 0),
+            stacked, one),
+        donate_argnums=0)
+
+    stack = []
+    for j, (mixer, ffn) in enumerate(blocks.stack_schedule(cfg)):
+        keys = jax.random.split(jax.random.fold_in(ks[1], j), n_periods)
+        layer = jax.jit(lambda k, mixer=mixer, ffn=ffn: quantize_tree(
+            jax.tree.map(lambda a: a[None],
+                         blocks.init_layer(k, mixer, ffn, cfg)),
+            cfg, qcfg=qcfg))
+        stacked = None
+        for i, k in enumerate(keys):
+            one = layer(k)
+            if stacked is None:
+                stacked = jax.tree.map(
+                    lambda a: jnp.zeros((n_periods,) + a.shape[1:], a.dtype),
+                    one)
+            stacked = put(stacked, one, i)
+        stack.append(stacked)
+
+    params = {"stack": stack,
+              "final_norm": init_norm(cfg.d_model, cfg.norm_type)}
+    if qcfg.quantize_embedding:
+        params["embed"] = _quantized_random_rows(
+            lm.init_embed, ks[0], cfg, "embed", qcfg, chunk_bytes)
+    else:
+        params["embed"] = jax.jit(
+            lambda k: lm.init_embed(k, cfg).astype(jnp.bfloat16))(ks[0])
+    if not cfg.tie_embeddings:
+        if qcfg.quantize_lm_head:
+            params["lm_head"] = _quantized_random_rows(
+                lm.init_lm_head, ks[2], cfg, "lm_head", qcfg, chunk_bytes)
+        else:
+            params["lm_head"] = jax.jit(lm.init_lm_head,
+                                        static_argnums=1)(ks[2], cfg)
+    return params
+
+
+def _quantized_random_rows(init_fn, key, cfg, kind: str, qcfg: QuantConfig,
+                           chunk_bytes: int):
+    """``quantize_unit(kind, init_fn(key, cfg), qcfg)`` for a [V, D]
+    matrix, quantized in row chunks when that is exact: the codebook is
+    static (not quantile) and whole blocks tile a row."""
+    import dataclasses
+
+    w = jax.jit(init_fn, static_argnums=1)(key, cfg)
+    V, D = w.shape
+    n = 1
+    if qcfg.dtype != "quantile" and D % qcfg.block_size == 0:
+        n = -(-w.nbytes // chunk_bytes)
+        while V % n:
+            n += 1
+    rows = V // n
+    part = jax.jit(lambda w, i: quantize_unit(
+        kind, jax.lax.dynamic_slice_in_dim(w, i * rows, rows), qcfg))
+    parts = [part(w, i) for i in range(n)]
+    del w
+    if n == 1:
+        return parts[0]
+
+    def cat(name):
+        leaves = [getattr(p, name) for p in parts]
+        return None if leaves[0] is None else jnp.concatenate(leaves, -1)
+
+    return dataclasses.replace(parts[0], packed=cat("packed"),
+                               scales=cat("scales"), means=cat("means"),
+                               quant_shape=(V, D))
+
+
 def quantizable_units(params, cfg, qcfg: QuantConfig | None = None) -> dict:
     """Enumerate the tree's quantizable units WITHOUT quantizing:
     {name: {"kind", "w", "n_params", "shape", "outlier_idx"}} — the

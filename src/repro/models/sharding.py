@@ -53,7 +53,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.qtensor import QuantizedTensor
 from repro.kernels import kv_dequant
-from repro.kernels.compat import shard_map_compat
 from repro.kernels.kv_dequant import kv_spec
 from repro.models import attention as attn_mod
 
@@ -247,8 +246,9 @@ class Sharder:
 
     def _qt_spec(self, keys, qt: QuantizedTensor):
         """Quantized leaves: output-row column-parallelism over `model`.
-        Structured storage shards the explicit row dim (-2); flat storage
-        shards the flat dim (contiguous rows) when it divides."""
+        Structured (K-major) storage shards its last dim, which indexes
+        the output rows; flat storage shards the flat dim (contiguous
+        rows) when it divides."""
         if self.mesh is None:
             return jax.tree.map(lambda _: None, qt)
         import dataclasses as _dc
@@ -268,14 +268,12 @@ class Sharder:
                 if qt.batch_shape[-1] % self.tp_size == 0:
                     lead[nb - 1] = tp
                 return self._ns(*lead)
-            if shardable and tp is not None:
+            if shardable and tp is not None and a.ndim >= 1:
                 out_rows = qt.quant_shape[0]
-                if structured_leaf and a.ndim >= 2:
-                    if out_rows % self.tp_size == 0:
-                        lead[-2] = tp
-                elif a.ndim >= 1:
-                    if out_rows % self.tp_size == 0 and a.shape[-1] % self.tp_size == 0:
-                        lead[-1] = tp
+                divides = a.shape[-1] % self.tp_size == 0
+                if out_rows % self.tp_size == 0 and (structured_leaf
+                                                     or divides):
+                    lead[-1] = tp
             return self._ns(*lead)
 
         st = qt.structured
@@ -487,11 +485,11 @@ class Sharder:
         pos_arr_spec = P(b_ax, s_ax) if per_slot else P(s_ax)
         pos_spec = P(b_ax) if pos_v.ndim else P()
         leaf_specs = tuple(P(b_ax, s_ax) for _ in keys)
-        out = shard_map_compat(
-            local, mesh,
+        out = jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(b_ax), P(b_ax), P(b_ax), pos_arr_spec, pos_spec)
             + leaf_specs,
-            out_specs=(P(b_ax), pos_arr_spec) + leaf_specs,
+            out_specs=(P(b_ax), pos_arr_spec) + leaf_specs, check_vma=False,
         )(q, k_new, v_new, cache["pos"], pos_v, *leaves)
         new_cache = dict(zip(keys, out[2:]))
         new_cache["pos"] = out[1]

@@ -30,11 +30,10 @@ three extra host-side things per jitted program:
    (benchmarks/common.timed_robust's estimator: noise only ever adds
    time).
 
-Hardware peaks default to the TPU v5e numbers in ``launch/mesh.py``
-(PEAK_FLOPS_BF16 / HBM_BW) — on the CPU container the roofline fraction
-is then "fraction of a v5e's roofline", a tiny but *consistent* number
-that still ranks programs and moves when a kernel regresses; pass
-``peak_flops=`` / ``hbm_bw=`` to rescale for other hardware.
+Hardware peaks come from the ``device_kind`` table in ``launch/mesh.py``
+(``device_peaks``); a device that is not in the table is an error, so a
+roofline fraction always names the chip it was measured on.  Pass
+``peak_flops=`` / ``hbm_bw=`` explicitly anywhere else (CPU tests do).
 
 Usage (docs/observability.md#step-profiler):
 
@@ -49,7 +48,6 @@ from __future__ import annotations
 
 import contextlib
 import time
-import warnings
 from dataclasses import dataclass, field
 
 __all__ = ["StepProfiler", "ProgramCost", "null_annotation"]
@@ -92,7 +90,7 @@ class _Session:
         self.profiler = profiler
         self.registry = registry
         self.labels = {k: str(v) for k, v in labels.items()}
-        self.costs: dict[str, ProgramCost | None] = {}
+        self.costs: dict[str, ProgramCost] = {}
 
     def annotation(self, name: str):
         """jax.profiler trace annotation for one dispatch — names the
@@ -101,26 +99,20 @@ class _Session:
 
         return TraceAnnotation(f"repro/{name}")
 
-    def ensure_costed(self, name, jitted, args) -> ProgramCost | None:
+    def ensure_costed(self, name, jitted, args) -> ProgramCost:
         """Cost `name` once: AOT lower+compile `jitted` at `args` and
         record its analytic FLOP/byte budget (static gauges included).
-        Idempotent and failure-sticky — a program whose cost extraction
-        raises is warned about once and never retried, and serving
-        continues unattributed."""
+        Idempotent; a failed costing raises — a profiled serve never
+        carries on unattributed."""
         if name in self.costs:
             return self.costs[name]
-        self.costs[name] = None  # sticky: no retry loop on failure
         from repro.utils.hlo import compiled_cost
 
-        try:
-            t0 = time.perf_counter()
-            compiled = jitted.lower(*args).compile()
-            cost = compiled_cost(compiled)
-            pc = ProgramCost(name=name, compile_s=time.perf_counter() - t0,
-                             **cost)
-        except Exception as e:  # pragma: no cover - backend-dependent
-            warnings.warn(f"profiler could not cost {name!r}: {e}")
-            return None
+        t0 = time.perf_counter()
+        compiled = jitted.lower(*args).compile()
+        cost = compiled_cost(compiled)
+        pc = ProgramCost(name=name, compile_s=time.perf_counter() - t0,
+                         **cost)
         self.costs[name] = pc
         lb = dict(self.labels, program=name)
         self.registry.gauge("profile_program_flops", **lb).set(pc.flops)
@@ -182,10 +174,11 @@ class StepProfiler:
     def __init__(self, *, peak_flops: float | None = None,
                  hbm_bw: float | None = None):
         if peak_flops is None or hbm_bw is None:
-            from repro.launch import mesh as mesh_mod
+            from repro.launch.mesh import device_peaks
 
-            peak_flops = peak_flops or mesh_mod.PEAK_FLOPS_BF16
-            hbm_bw = hbm_bw or mesh_mod.HBM_BW
+            peaks = device_peaks()
+            peak_flops = peak_flops or peaks["flops_bf16"]
+            hbm_bw = hbm_bw or peaks["hbm_bw"]
         self.peak_flops = float(peak_flops)
         self.hbm_bw = float(hbm_bw)
         self.sessions: list[_Session] = []

@@ -820,21 +820,31 @@ class Server:
             self._temps[slot] = req.temperature
         return 1
 
-    def _decode_once(self) -> int:
+    def _decode_call(self):
+        """(jitted decode step, its arguments) for the pool as it stands."""
         tok = jnp.asarray(np.where(self.pool.active, self._cur_tok, 0),
                           jnp.int32)
         pos = self.pool.pos_vector()
         temps = jnp.asarray(np.where(self.pool.active, self._temps, 0.0),
                             jnp.float32)
         self._key, sub = jax.random.split(self._key)
-        tel = self.telemetry
         ds_args = (self.params, tok, self.pool.caches, pos, sub, temps)
-        step_fn = self._step
         if self._paged:
             # the table snapshot rides along as a traced argument — the
             # compiled step is table-agnostic, so admissions never recompile
-            ds_args = ds_args + (jnp.asarray(self.pool.page_map),)
-            step_fn = self._step_paged
+            return self._step_paged, ds_args + (
+                jnp.asarray(self.pool.page_map),)
+        return self._step, ds_args
+
+    def lower_decode(self):
+        """The decode step lowered for the current pool (``.as_text()``
+        shows which kernels the served program calls)."""
+        step_fn, ds_args = self._decode_call()
+        return step_fn.lower(*ds_args)
+
+    def _decode_once(self) -> int:
+        tel = self.telemetry
+        step_fn, ds_args = self._decode_call()
         if self._prof is not None:
             self._prof.ensure_costed("decode_step", step_fn, ds_args)
         if tel.enabled:

@@ -64,7 +64,9 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.models import lm
     from repro.models.sharding import Sharder
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = dataclasses.replace(
         get_arch("h2o-danube-3-4b").reduced(),
         n_heads=4, n_kv_heads=2, d_model=64, sliding_window=0,
@@ -102,8 +104,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_sharded_decode_matches_unsharded_subprocess():
-    # kernels/compat.shard_map_compat covers both the top-level (>= 0.5)
-    # and the experimental shard_map API, so no jax-version skip here
+    # jax.shard_map is top-level on the installed jax: no version skip
     script = _SUBPROCESS_SCRIPT.format(src=SRC)
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=600)
@@ -135,7 +136,9 @@ def test_elastic_remesh_subprocess():
             mgr.save(3, state)
             _, restored, _ = mgr.restore(state)
         # re-mesh the restored host state onto a (4, 2) mesh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((4, 2), ("data", "model"))
         placed, sharder = remesh_state(restored, cfg, mesh)
         ok = jax.tree.all(jax.tree.map(
             lambda a, b: jnp.allclose(jnp.asarray(a, jnp.float32),

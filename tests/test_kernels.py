@@ -32,7 +32,7 @@ def test_qmatmul_kernel_matches_ref(bits, dtype, M, K, N, block):
     w = jax.random.normal(jax.random.fold_in(key, 1), (K, N), jnp.float32) * 0.05
     op = ops.prepare_operand(w, bits=bits, dtype=dtype, block_size=block)
     y_ref = qmatmul_ref(x, op)
-    y_ker = ops.qmatmul(x, op, use_kernel=True, interpret=True)
+    y_ker = ops.qmatmul(x, op, interpret=True)
     rel = float(jnp.max(jnp.abs(y_ker - y_ref))) / (
         float(jnp.max(jnp.abs(y_ref))) + 1e-9
     )
@@ -46,7 +46,7 @@ def test_qmatmul_input_dtypes(in_dtype):
     w = jax.random.normal(jax.random.fold_in(key, 1), (256, 128)) * 0.05
     op = ops.prepare_operand(w, bits=4, dtype="float", block_size=64)
     y_ref = qmatmul_ref(x, op)
-    y_ker = ops.qmatmul(x, op, use_kernel=True, interpret=True)
+    y_ker = ops.qmatmul(x, op, interpret=True)
     assert y_ker.dtype == in_dtype
     assert jnp.allclose(
         y_ker.astype(jnp.float32), y_ref.astype(jnp.float32), atol=0.25, rtol=0.05
@@ -64,7 +64,7 @@ def test_qmatmul_ragged_shapes_padding():
     )
     xp = jnp.pad(x, ((0, 0), (0, 56)))
     y_ref = qmatmul_ref(xp, op)
-    y_ker = ops.qmatmul(xp, op, use_kernel=True, interpret=True)
+    y_ker = ops.qmatmul(xp, op, interpret=True)
     assert jnp.allclose(y_ker, y_ref, atol=1e-4)
 
 
@@ -77,8 +77,7 @@ def test_qmatmul_matches_model_linear_path():
     w = jax.random.normal(key, (256, 192)) * 0.05
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 256))
     qt = _quantize_matrix(w, QuantConfig(bits=4, dtype="float", block_size=64))
-    y_kernel = ops.qmatmul(x, ops.operand_from_qtensor(qt),
-                           use_kernel=True, interpret=True)
+    y_kernel = ops.qmatmul(x, ops.operand_from_qtensor(qt), interpret=True)
     y_model = linear(x, qt)
     assert jnp.allclose(y_kernel, y_model.astype(jnp.float32), atol=2e-2)
 
@@ -87,8 +86,8 @@ def test_qmatmul_matches_model_linear_path():
 def test_quantize_kernel_matches_ref(bits, dtype):
     cb = make_codebook(dtype, bits)
     x = jax.random.normal(jax.random.PRNGKey(bits), (2048,)) * 2
-    c1, s1 = ops.quantize_blocks(x, cb, 64, use_kernel=True, interpret=True)
-    c2, s2 = ops.quantize_blocks(x, cb, 64, use_kernel=False)
+    c1, s1 = ops.quantize_blocks(x, cb, 64, interpret=True)
+    c2, s2 = quantize_blocks_ref(x.reshape(-1, 64), cb)
     assert jnp.array_equal(c1, c2)
     assert jnp.allclose(s1, s2, rtol=1e-6)
 
@@ -98,6 +97,36 @@ def test_quantize_kernel_matches_core_blockwise():
 
     cb = make_codebook("float", 4)
     x = jax.random.normal(jax.random.PRNGKey(5), (4096,))
-    codes, scales = ops.quantize_blocks(x, cb, 64, use_kernel=True, interpret=True)
+    codes, scales = ops.quantize_blocks(x, cb, 64, interpret=True)
     q = blockwise.encode(x, cb, 64)
     assert jnp.array_equal(codes.astype(jnp.uint8), q.codes)
+
+
+def test_pallas_route_is_a_stated_rule(monkeypatch):
+    """Where the fused backend is the Pallas kernel (TPU), a matrix it
+    cannot tile — odd bit-widths, dims off the (8, 128) grid — is not
+    fused-eligible and takes the dequant path; qwen2-7b's matrices at
+    4 and 8 bits are, whole and per TP shard.  Steered here by patching
+    the backend choice; the compiles themselves are in
+    test_tpu_compile.py."""
+    from repro.configs import QuantConfig
+    from repro.models.layers import resolve_matmul_mode
+    from repro.models.quantize import _quantize_matrix
+
+    def qt(K, N, bits):
+        w = jnp.zeros((K, N), jnp.float32)
+        return _quantize_matrix(w, QuantConfig(bits=bits, block_size=64))
+
+    assert ops.qt_fused_eligible(qt(192, 96, 3))       # jnp backend: all
+    monkeypatch.setattr(ops, "fused_backend", lambda: "pallas")
+    assert ops.qt_fused_eligible(qt(512, 512, 4))
+    assert ops.qt_fused_eligible(qt(512, 256, 8))
+    assert not ops.qt_fused_eligible(qt(640, 256, 3))  # odd width
+    assert not ops.qt_fused_eligible(qt(512, 96, 4))   # N off 128 lanes
+    assert not ops.qt_fused_eligible(qt(192, 256, 4))  # K off the tile
+    assert resolve_matmul_mode("auto", qt(640, 256, 5)) == "dequant_einsum"
+    for K, N in [(3584, 3584), (3584, 512), (3584, 18944), (18944, 3584),
+                 (3584, 152064)]:
+        for bits in (4, 8):
+            assert ops.pallas_fusable(bits, 64, N, K)
+            assert ops.pallas_fusable(bits, 64, N // 4, K)  # 4-way TP

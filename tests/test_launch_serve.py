@@ -65,6 +65,10 @@ def _args(*argv):
     (("--max-preemptions", "2", "--priorities", "1"), "--priorities"),
     # the profiler's gauges need a telemetry sink to land in
     (("--profile",), "--profile"),
+    (("--peaks", "1e12,1e11", "--metrics-out", "m.prom"), "--profile"),
+    (("--profile", "--peaks", "fast", "--metrics-out", "m.prom"), "--peaks"),
+    (("--profile", "--peaks", "0,1e11", "--metrics-out", "m.prom"),
+     "positive"),
     # paged-cache flags: continuous-only, exclusive with chunking/mesh
     (("--mode", "static", "--paged"), "static"),
     (("--page-size", "8"), "--paged"),
@@ -108,6 +112,8 @@ def test_mesh_flag_validated():
     ("--profile", "--metrics-out", "m.prom"),
     ("--profile", "--trace-out", "t.jsonl"),
     ("--mode", "static", "--profile", "--metrics-out", "m.prom"),
+    ("--profile", "--peaks", "197e12,819e9", "--metrics-out", "m.prom"),
+    ("--seed", "7"),
     ("--paged",),
     ("--paged", "--page-size", "8", "--pages", "32", "--kv-bits", "4"),
     ("--paged", "--priorities", "2", "--max-preemptions", "1"),
@@ -177,7 +183,7 @@ def test_profile_flag_serves_with_roofline_gauges(tmp_path, capsys):
     serve_mod.main(["--arch", "tiny-160k", "--mode", "continuous",
                     "--kv-bits", "4", "--num-requests", "3",
                     "--num-slots", "2", "--max-new", "4", "--profile",
-                    "--metrics-out", str(mpath)])
+                    "--peaks", "1e12,1e11", "--metrics-out", str(mpath)])
     out = capsys.readouterr().out
     assert "profiler (" in out and "decode_step" in out, out
     text = mpath.read_text()

@@ -159,6 +159,19 @@ def test_profiler_roofline_math_and_null_annotation():
     assert NOOP.profiler is None
 
 
+def test_profiler_peaks_come_from_the_device_table():
+    """Peaks are looked up by device_kind; a device missing from the
+    table (the CPU here) is an error, never a borrowed v5e default."""
+    from repro.launch.mesh import device_peaks
+
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        StepProfiler()
+    prof = StepProfiler(peak_flops=1e12, hbm_bw=1e11)
+    assert (prof.peak_flops, prof.hbm_bw) == (1e12, 1e11)
+
+
 def test_profiler_failure_is_sticky_and_warns():
     prof = StepProfiler(peak_flops=1e12, hbm_bw=1e11)
     sess = prof.session(MetricsRegistry(), kv_bits="16", matmul_mode="auto")
@@ -167,10 +180,12 @@ def test_profiler_failure_is_sticky_and_warns():
         def lower(self, *a):
             raise RuntimeError("no lowering today")
 
-    with pytest.warns(UserWarning, match="could not cost 'bad'"):
-        assert sess.ensure_costed("bad", Boom(), ()) is None
-    # sticky: the second call neither retries nor warns again
-    assert sess.ensure_costed("bad", Boom(), ()) is None
+    # a profiled serve never carries on unattributed: the failure raises,
+    # every time (nothing is cached for the program)
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="no lowering today"):
+            sess.ensure_costed("bad", Boom(), ())
+    assert "bad" not in sess.costs
     sess.observe("bad", 1e-3)  # uncosted observe is histogram-only
     assert sess.summary() == []
 
@@ -190,7 +205,8 @@ def test_tokens_identical_with_profiler_on_vs_off(params):
         res = srv.run_until_drained()
         return [res[r] for r in ids]
 
-    tel = Telemetry(profiler=StepProfiler())
+    # explicit peaks: the CPU is not in launch/mesh.DEVICE_PEAKS
+    tel = Telemetry(profiler=StepProfiler(peak_flops=1e12, hbm_bw=1e11))
     assert serve(tel) == serve(NOOP)
     # the profiled run costed + attributed the real serving programs
     rows = tel.profiler.summary()
@@ -202,7 +218,7 @@ def test_tokens_identical_with_profiler_on_vs_off(params):
 
     # static Engine: same contract
     ep = jnp.asarray(_prompts(2, 7, seed=80))
-    tel_e = Telemetry(profiler=StepProfiler())
+    tel_e = Telemetry(profiler=StepProfiler(peak_flops=1e12, hbm_bw=1e11))
     out_p = Engine(params, CFG, max_seq_len=14,
                    telemetry=tel_e).generate(ep, 5)
     out_off = Engine(params, CFG, max_seq_len=14).generate(ep, 5)

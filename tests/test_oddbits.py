@@ -84,7 +84,8 @@ def test_structured_storage_repacks_word_tails():
     flat3 = quantize_tensor(x, bits=3, dtype="float", block_size=16)
     qt3 = to_structured(flat3)
     assert qt3.structured  # 64 % 10 != 0 -> row-aligned repack
-    assert qt3.packed.shape == (16, packing.packed_size(64, 3))
+    # K-major storage: each row's words run down a column
+    assert qt3.packed.shape == (packing.packed_size(64, 3), 16)
     assert jnp.array_equal(
         dequantize_tensor(qt3, out_dtype=jnp.float32),
         dequantize_tensor(flat3, out_dtype=jnp.float32),

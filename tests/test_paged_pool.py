@@ -292,9 +292,10 @@ def test_gather_pages_reconstructs_table_order():
 
 
 def test_cache_spec_tree_paged_keeps_token_axis_unsharded():
+    from repro.launch.mesh import make_mesh
     from repro.models.sharding import Sharder
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sharder = Sharder(mesh, CFG, replicate_params_below=0)
     caches = lm.init_caches(CFG, 8, 4, per_slot=True)  # 8 pages of 4
     paged = sharder.cache_spec_tree(caches, 8, paged=True)

@@ -141,3 +141,45 @@ def test_dequantize_params_respects_original_dtype(tiny):
             assert jax.tree_util.keystr(pa) == jax.tree_util.keystr(pb)
             assert a.shape == b.shape, pa
             assert a.dtype == b.dtype, (pa, a.dtype, b.dtype)
+
+
+@pytest.mark.parametrize("arch,reduce", [("tiny-650k", False),
+                                         ("qwen2-7b", True)])
+@pytest.mark.parametrize("qcfg", [
+    QuantConfig(bits=4, dtype="float", block_size=64),
+    QuantConfig(bits=3, dtype="quantile", block_size=32,
+                quantize_embedding=True),
+], ids=["float4-b64", "quantile3-b32-embed"])
+def test_streamed_build_equals_dense_quantize(arch, reduce, qcfg):
+    """init_quantized_params builds layer by layer (lm_head and a
+    quantized embedding in row chunks — a tiny chunk_bytes forces many)
+    yet yields the tree quantize_params(lm.init_params(...)) yields: same
+    structure, metadata, codes, scales and codebooks, bit for bit.  The
+    reference is compiled too: XLA's fused and op-by-op random normals
+    can differ in the last ulp.  A dense embedding is kept in bf16, the
+    dtype the forward reads it in."""
+    from repro.models.quantize import init_quantized_params
+
+    cfg = get_arch(arch)
+    cfg = cfg.reduced() if reduce else cfg
+    key = jax.random.PRNGKey(3)
+    ref = jax.jit(lambda k: quantize_params(lm.init_params(k, cfg), qcfg,
+                                            cfg))(key)
+    got = init_quantized_params(key, cfg, qcfg, chunk_bytes=4096)
+    if not isinstance(ref["embed"], QuantizedTensor):
+        assert got["embed"].dtype == jnp.bfloat16
+        ref["embed"] = ref["embed"].astype(jnp.bfloat16)
+    leaves_r, tree_r = jax.tree_util.tree_flatten(ref)
+    leaves_g, tree_g = jax.tree_util.tree_flatten(got)
+    assert tree_r == tree_g
+    for a, b in zip(leaves_r, leaves_g):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert bool(jnp.all(a == b))
+
+
+def test_streamed_build_rejects_proxy_quantization():
+    from repro.models.quantize import init_quantized_params
+
+    with pytest.raises(ValueError, match="outlier_pct"):
+        init_quantized_params(jax.random.PRNGKey(0), get_arch("tiny-160k"),
+                              QuantConfig(bits=4, outlier_pct=0.02))

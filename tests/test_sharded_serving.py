@@ -49,7 +49,9 @@ _PRELUDE = """
     from repro.models.sharding import Sharder, SeqShardFallbackWarning
     from repro.serving import Engine, Server, KV_LOGIT_TOL
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((2, 4), ("data", "model"))
     # tiny-650k: 4 heads divide the 4-way model axis (tiny-160k's 2
     # would force a pathological feature-split head layout), and it is
     # in the tiny family KV_LOGIT_TOL was calibrated on
