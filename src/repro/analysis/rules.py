@@ -426,7 +426,8 @@ class TraceSchemaRule:
                 if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute):
                     if (node.args and isinstance(node.args[0], ast.Constant)
                             and isinstance(node.args[0].value, str)):
-                        if node.func.attr == "span":
+                        # Telemetry.scope(name) records a span too
+                        if node.func.attr in ("span", "scope"):
                             span_sites.setdefault(node.args[0].value, []).append(
                                 (pf, node.lineno))
                         elif node.func.attr == "event":
@@ -460,8 +461,9 @@ class TraceSchemaRule:
             findings.append(Finding(
                 rule=self.id, path=decl_pf.path, line=decl_lines.get(name, 1),
                 symbol=name,
-                message=f"SPAN_NAMES declares '{name}' but no .span() site "
-                        "emits it — dead schema entries are errors"))
+                message=f"SPAN_NAMES declares '{name}' but no .span() or "
+                        ".scope() site emits it — dead schema entries are "
+                        "errors"))
         for name in sorted(events - set(event_sites)):
             findings.append(Finding(
                 rule=self.id, path=decl_pf.path, line=decl_lines.get(name, 1),

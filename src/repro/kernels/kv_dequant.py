@@ -195,6 +195,8 @@ def dequant_rows_pallas(packed, scales, spec: KVQuantSpec, feat: int, *,
             dimension_semantics=("parallel",),
         ),
         interpret=resolve_interpret(interpret),
+        # names the call in the chip trace's op text (kernel_metadata)
+        metadata={"kernel": "_dequant_kernel"},
     )(packed, scales, cb2)
     return out[:R]
 

@@ -159,4 +159,6 @@ def qmatmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # names the call in the chip trace's op text (kernel_metadata)
+        metadata={"kernel": "_qmatmul_kernel"},
     )(x, packed, scales, codebook.astype(jnp.float32))

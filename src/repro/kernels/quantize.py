@@ -64,4 +64,6 @@ def quantize_blocks_pallas(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        # names the call in the chip trace's op text (kernel_metadata)
+        metadata={"kernel": "_quantize_kernel"},
     )(x_blocks, bounds)

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 
 from repro.models import blocks, lm
 from repro.models.sharding import check_decode_capability
-from repro.serving.profiler import null_annotation
 from repro.serving.telemetry import NOOP, record_quant_health, record_tree_bits
 
 #: stated per-token logit tolerance of a k-bit KV cache vs the bf16-cache
@@ -193,8 +192,6 @@ class Engine:
                                    kv_bits=str(cfg.kv_bits),
                                    matmul_mode=cfg.matmul_mode)
                       if telemetry.enabled and prof is not None else None)
-        self._annot = (self._prof.annotation if self._prof is not None
-                       else null_annotation)
 
     def _place_caches(self, caches, batch: int):
         """Move the prefill-produced caches onto their sequence-sharded
@@ -218,28 +215,26 @@ class Engine:
             # never pollutes the timed window
             self._prof.ensure_costed(pf_name, self._prefill,
                                      (self.params, prompts))
-        if tel.enabled:
-            t_start = tel.now()
-        with self._annot(pf_name):
+        with tel.scope("prefill", step=0, slot=-1, prompt_len=S,
+                       padded_len=S) as pf:
             logits, caches = self._prefill(self.params, prompts)
-        caches = self._place_caches(caches, B)
-        # the first token goes through the same temperature/categorical
-        # path as decode steps (it used to be unconditionally greedy)
-        key, sub = jax.random.split(key)
-        tok = self._first(logits, sub, jnp.float32(temperature))
+            caches = self._place_caches(caches, B)
+            # the first token goes through the same temperature/categorical
+            # path as decode steps (it used to be unconditionally greedy)
+            key, sub = jax.random.split(key)
+            tok = self._first(logits, sub, jnp.float32(temperature))
+            if tel.enabled:
+                # host-side fence at the dispatch boundary; the jitted
+                # prefill/step programs are untouched (docs/observability.md)
+                jax.block_until_ready(tok)
         if tel.enabled:
-            # host-side fence at the dispatch boundary; the jitted
-            # prefill/step programs are untouched (docs/observability.md)
-            jax.block_until_ready(tok)
-            t_tok = tel.now()
+            t_tok = pf.t1
             if self._prof is not None:
-                self._prof.observe(pf_name, t_tok - t_start)
-            tel.observe("serve_prefill_seconds", t_tok - t_start)
-            tel.observe("serve_ttft_seconds", t_tok - t_start)
+                self._prof.observe(pf_name, t_tok - pf.t0)
+            tel.observe("serve_prefill_seconds", t_tok - pf.t0)
+            tel.observe("serve_ttft_seconds", t_tok - pf.t0)
             tel.inc("serve_prefills_total")
             tel.inc("serve_tokens_total", B)
-            tel.span("prefill", t_start, t_tok, step=0,
-                     slot=-1, prompt_len=S, padded_len=S)
         done = (tok == self.eos_id) if self.eos_id is not None else jnp.zeros((B,), bool)
         out = [tok]
         ds_name = f"decode_step[{B}]"
@@ -249,22 +244,19 @@ class Engine:
                        jnp.float32(temperature), done)
             if self._prof is not None:
                 self._prof.ensure_costed(ds_name, self._step, ds_args)
-            if tel.enabled:
-                t0 = tel.now()
-            with self._annot(ds_name):
+            with tel.scope("decode_step", step=t, n_active=B,
+                           batch_fill=1.0) as ds:
                 tok, caches, done = self._step(*ds_args)
+                if tel.enabled:
+                    jax.block_until_ready(tok)
             if tel.enabled:
-                jax.block_until_ready(tok)
-                t1 = tel.now()
                 if self._prof is not None:
-                    self._prof.observe(ds_name, t1 - t0)
-                tel.observe("serve_decode_step_seconds", t1 - t0)
-                tel.observe("serve_itl_seconds", t1 - t_tok)
-                t_tok = t1
+                    self._prof.observe(ds_name, ds.t1 - ds.t0)
+                tel.observe("serve_decode_step_seconds", ds.t1 - ds.t0)
+                tel.observe("serve_itl_seconds", ds.t1 - t_tok)
+                t_tok = ds.t1
                 tel.inc("serve_decode_steps_total")
                 tel.inc("serve_tokens_total", B)
-                tel.span("decode_step", t0, t1, step=t, n_active=B,
-                         batch_fill=1.0)
             out.append(tok)
             if self.eos_id is not None and bool(jnp.all(done)):
                 break

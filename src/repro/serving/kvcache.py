@@ -188,6 +188,19 @@ class SlotKVCache:
         is a masked zero — see models/attention.py)."""
         return jnp.asarray(np.where(self.active, self.next_pos, -1), jnp.int32)
 
+    def live_positions(self) -> int:
+        """Positions holding tokens that a decode step attends over: the
+        sum of pos + 1 across the decoding rows (idle and mid-chunk rows
+        sit at -1 and hold none)."""
+        pos = self.next_pos[self.active]
+        return int(np.sum(pos[pos >= 0] + 1))
+
+    def kv_read_counts(self) -> tuple[int, int]:
+        """(kv_read, kv_live) of the decode step built from this pool:
+        the positions its KV read covers, every row's whole cache_len
+        (``pos_vector`` masks, it does not narrow), and the live ones."""
+        return self.num_slots * self.cache_len, self.live_positions()
+
     def room(self, slot: int) -> int:
         """Decode positions left before this slot hits the cache budget."""
         return self.cache_len - int(self.next_pos[slot])

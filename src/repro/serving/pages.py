@@ -369,6 +369,14 @@ class PagedKVPool(SlotKVCache):
         if telemetry.enabled:
             self.record_footprint()
 
+    def kv_read_counts(self, page_map) -> tuple[int, int]:
+        """(kv_read, kv_live) of the decode step that gathers through
+        `page_map`, the table passed to it: the read covers every page of
+        every row (``paged_decode_attn_fn`` gathers the whole table,
+        live or not), so its shape times the page size."""
+        rows, pages = page_map.shape
+        return rows * pages * self.page_size, self.live_positions()
+
     # -- admission planning (host) ---------------------------------------
     def pages_needed(self, prompt_len: int, max_new: int) -> int:
         return self.allocator.pages_needed(prompt_len, max_new)

@@ -4,7 +4,7 @@ The serving telemetry (telemetry.py) measures *how long* each engine
 step takes; this module says *how fast that is relative to the
 hardware*.  A ``StepProfiler`` attached to a recording ``Telemetry``
 (``Telemetry(profiler=StepProfiler())``) makes the Server/Engine do
-three extra host-side things per jitted program:
+two extra host-side things per jitted program:
 
 1. **Cost the program once.**  On the first dispatch the program is
    AOT-lowered and compiled (``jitted.lower(*args).compile()``) and its
@@ -14,11 +14,7 @@ three extra host-side things per jitted program:
    launch dry-run manifests use.  This is one extra compile per program
    per profiled serve (a profiling cost, never paid by an unprofiled
    serve).
-2. **Annotate the dispatch.**  Each dispatch runs inside a
-   ``jax.profiler.TraceAnnotation("repro/<program>")`` scope, so a
-   device timeline captured with ``jax.profiler.trace(...)`` shows the
-   engine-step structure by name.
-3. **Attribute the measured time.**  The wall time the serving code
+2. **Attribute the measured time.**  The wall time the serving code
    already measures (host-side, behind the existing
    ``block_until_ready`` fences — the jitted programs are byte-identical
    with the profiler on or off) is divided into the static cost:
@@ -42,24 +38,18 @@ Usage (docs/observability.md#step-profiler):
     ...serve...
     print(tel.profiler.format_summary())
     # or: launch/serve.py --profile --metrics-out metrics.prom
+
+Naming the dispatches on a device timeline is not the profiler's job:
+every recording ``Telemetry`` does it through ``Telemetry.scope``
+(``serve/<name>`` annotations), with or without a profiler.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from dataclasses import dataclass, field
 
-__all__ = ["StepProfiler", "ProgramCost", "null_annotation"]
-
-_NULL_CTX = contextlib.nullcontext()
-
-
-def null_annotation(name: str):
-    """The no-profiler stand-in for ``session.annotation``: a shared
-    reusable null context, so dispatch sites can unconditionally write
-    ``with self._annot("decode_step"):``."""
-    return _NULL_CTX
+__all__ = ["StepProfiler", "ProgramCost"]
 
 
 @dataclass
@@ -91,13 +81,6 @@ class _Session:
         self.registry = registry
         self.labels = {k: str(v) for k, v in labels.items()}
         self.costs: dict[str, ProgramCost] = {}
-
-    def annotation(self, name: str):
-        """jax.profiler trace annotation for one dispatch — names the
-        program on any device timeline being captured."""
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(f"repro/{name}")
 
     def ensure_costed(self, name, jitted, args) -> ProgramCost:
         """Cost `name` once: AOT lower+compile `jitted` at `args` and

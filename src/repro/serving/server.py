@@ -97,7 +97,6 @@ from repro.serving.pages import (
     paged_decode_attn_fn,
     scatter_pages,
 )
-from repro.serving.profiler import null_annotation
 from repro.serving.scheduler import Request, Scheduler
 from repro.serving.telemetry import (
     NOOP,
@@ -342,16 +341,14 @@ class Server:
             self._step_paged = jax.jit(step_paged, donate_argnums=(2,))
 
         # optional roofline attribution (serving/profiler.py): a private
-        # cost-cache session labelled with this server's quant config, and
-        # the annotation hook dispatch sites wrap.  All host-side — the
-        # jitted programs above are byte-identical with the profiler on.
+        # cost-cache session labelled with this server's quant config.
+        # All host-side — the jitted programs above are byte-identical
+        # with the profiler on.
         prof = getattr(telemetry, "profiler", None)
         self._prof = (prof.session(telemetry.registry,
                                    kv_bits=str(cfg.kv_bits),
                                    matmul_mode=cfg.matmul_mode)
                       if telemetry.enabled and prof is not None else None)
-        self._annot = (self._prof.annotation if self._prof is not None
-                       else null_annotation)
 
         if prefill_chunk is not None:
             # dense bf16 workspace config for the chunk K/V (the packed
@@ -483,11 +480,20 @@ class Server:
         when the pool is full and preemption is enabled), advance one
         prefill chunk per chunking slot, then run one decode step over
         the non-chunking slots.  Returns the number of useful tokens
-        produced (admission prefills included)."""
-        produced = self._admit()
-        produced += self._advance_chunks()
-        if len(self.scheduler.running) > len(self._chunking):
-            produced += self._decode_once()
+        produced (admission prefills included).
+
+        Telemetry scopes cover the whole call: ``step`` around it,
+        ``admit`` around admission, then ``decode_args`` / ``decode_step``
+        / ``emit`` inside the decode (docs/observability.md)."""
+        tel = self.telemetry
+        with tel.scope("step", step=self.steps) as sc:
+            with tel.scope("admit", step=self.steps):
+                produced = self._admit()
+            produced += self._advance_chunks()
+            if len(self.scheduler.running) > len(self._chunking):
+                produced += self._decode_once()
+            if tel.enabled:
+                sc.attrs["produced"] = produced
         self.steps += 1
         return produced
 
@@ -617,32 +623,29 @@ class Server:
                 # AOT cost extraction happens BEFORE t0 so the one-time
                 # compile never pollutes the timed window
                 self._prof.ensure_costed(pf_name, pf_fn, pf_args)
-            if tel.enabled:
-                t0 = tel.now()
-                if req.t_submit is not None:
-                    tel.span("queue_wait", req.t_submit, t0,
-                             request_id=req.id, step=self.steps,
-                             steps=float(self.steps - req.arrival_time))
-            with self._annot(pf_name):
+            if tel.enabled and req.t_submit is not None:
+                tel.span("queue_wait", req.t_submit, tel.now(),
+                         request_id=req.id, step=self.steps,
+                         steps=float(self.steps - req.arrival_time))
+            with tel.scope("prefill", request_id=req.id, step=self.steps,
+                           slot=slot, prompt_len=L, padded_len=Sb) as pf:
                 tok, new_pool = pf_fn(*pf_args)
-            self.pool.install_prefill(slot, new_pool, L)
-            if self._paged:
-                # publish the full prompt pages for COW before anything
-                # can preempt this slot (spill retains sealed pages only)
-                self.pool.seal_slot(slot)
+                self.pool.install_prefill(slot, new_pool, L)
+                if self._paged:
+                    # publish the full prompt pages for COW before anything
+                    # can preempt this slot (spill retains sealed pages only)
+                    self.pool.seal_slot(slot)
+                if tel.enabled:
+                    # fence at the dispatch boundary: host-side timing
+                    # only, the compiled prefill is untouched
+                    jax.block_until_ready(tok)
             if tel.enabled:
-                # fence at the dispatch boundary: host-side timing only,
-                # the compiled prefill is untouched
-                jax.block_until_ready(tok)
-                t1 = tel.now()
+                dt = pf.t1 - pf.t0
                 if self._prof is not None:
-                    self._prof.observe(pf_name, t1 - t0)
-                tel.observe("serve_prefill_seconds", t1 - t0)
+                    self._prof.observe(pf_name, dt)
+                tel.observe("serve_prefill_seconds", dt)
                 tel.observe("serve_prefill_pad_frac", (Sb - L) / Sb)
                 tel.inc("serve_prefills_total")
-                tel.span("prefill", t0, t1, request_id=req.id,
-                         step=self.steps, slot=slot, prompt_len=L,
-                         padded_len=Sb)
                 self._n_admitted += 1
                 if (self._probe is not None
                         and (self._n_admitted - 1) % tel.kv_probe_every == 0):
@@ -673,44 +676,44 @@ class Server:
         stream is identical to an unpreempted run (greedy)."""
         victim = self.scheduler.running[slot]
         tel = self.telemetry
-        t0 = tel.now() if tel.enabled else 0.0
-        spill = self.pool.spill_slot(slot)
-        spill["cur_tok"] = int(self._cur_tok[slot])
-        self._spilled[victim.id] = spill
-        self.scheduler.preempt(slot, self.steps)
-        self.pool.free(slot)
-        if tel.enabled:
-            t1 = tel.now()
-            if self._paged:
-                tel.event("page_release", t0, request_id=victim.id,
-                          step=self.steps, n_pages=spill["n_private"],
-                          reason="preempt")
-            tel.event("preempt", t0, request_id=victim.id, step=self.steps,
-                      slot=slot, by=by.id, n_tokens=len(victim.tokens))
-            tel.span("spill", t0, t1, request_id=victim.id, step=self.steps,
-                     slot=slot, bytes_packed=spill["bytes_packed"],
-                     bytes_logical=spill["bytes_logical"])
+        with tel.scope("spill", request_id=victim.id, step=self.steps,
+                       slot=slot) as sp:
+            spill = self.pool.spill_slot(slot)
+            spill["cur_tok"] = int(self._cur_tok[slot])
+            self._spilled[victim.id] = spill
+            self.scheduler.preempt(slot, self.steps)
+            self.pool.free(slot)
+            if tel.enabled:
+                # inside the scope: the lifecycle wants page_release and
+                # preempt ahead of the spill span (trace.py)
+                if self._paged:
+                    tel.event("page_release", sp.t0, request_id=victim.id,
+                              step=self.steps, n_pages=spill["n_private"],
+                              reason="preempt")
+                tel.event("preempt", sp.t0, request_id=victim.id,
+                          step=self.steps, slot=slot, by=by.id,
+                          n_tokens=len(victim.tokens))
+                sp.attrs.update(bytes_packed=spill["bytes_packed"],
+                                bytes_logical=spill["bytes_logical"])
 
     def _resume(self, req: Request, slot: int) -> None:
         """Write a preempted request's spilled rows back into its new
         slot and rejoin the decode batch exactly where it left off."""
         spill = self._spilled.pop(req.id)
         tel = self.telemetry
-        t0 = tel.now() if tel.enabled else 0.0
-        self.pool.restore_slot(slot, spill)
-        self._cur_tok[slot] = spill["cur_tok"]
-        self._temps[slot] = req.temperature
-        if tel.enabled:
-            jax.block_until_ready(
-                jax.tree_util.tree_leaves(self.pool.caches)[0])
-            t1 = tel.now()
-            if self._paged:
-                tel.event("page_alloc", t0, request_id=req.id,
-                          step=self.steps, slot=slot,
-                          n_pages=spill["n_private"],
-                          n_shared=spill["n_retained"])
-            tel.span("restore", t0, t1, request_id=req.id, step=self.steps,
-                     slot=slot, bytes_packed=spill["bytes_packed"])
+        with tel.scope("restore", request_id=req.id, step=self.steps,
+                       slot=slot, bytes_packed=spill["bytes_packed"]) as rs:
+            self.pool.restore_slot(slot, spill)
+            self._cur_tok[slot] = spill["cur_tok"]
+            self._temps[slot] = req.temperature
+            if tel.enabled:
+                jax.block_until_ready(
+                    jax.tree_util.tree_leaves(self.pool.caches)[0])
+                if self._paged:
+                    tel.event("page_alloc", rs.t0, request_id=req.id,
+                              step=self.steps, slot=slot,
+                              n_pages=spill["n_private"],
+                              n_shared=spill["n_retained"])
 
     def _start_chunked(self, req: Request, slot: int, L: int, Sb: int) -> None:
         """Begin a chunked admission: allocate the dense bf16 workspace
@@ -756,20 +759,18 @@ class Server:
             ck_name = f"prefill_chunk[{st.Sb}]"
             if self._prof is not None:
                 self._prof.ensure_costed(ck_name, self._chunk_step, ck_args)
-            if tel.enabled:
-                t0 = tel.now()
-            with self._annot(ck_name):
+            with tel.scope("prefill_chunk", request_id=st.req.id,
+                           step=self.steps, slot=slot, chunk=st.next,
+                           chunk_start=c0, chunk_len=C,
+                           padded_len=st.Sb) as ck:
                 h, st.workspace = self._chunk_step(*ck_args)
+                if tel.enabled:
+                    jax.block_until_ready(h)
             if tel.enabled:
-                jax.block_until_ready(h)
-                t1 = tel.now()
                 if self._prof is not None:
-                    self._prof.observe(ck_name, t1 - t0)
-                tel.observe("serve_prefill_chunk_seconds", t1 - t0)
+                    self._prof.observe(ck_name, ck.t1 - ck.t0)
+                tel.observe("serve_prefill_chunk_seconds", ck.t1 - ck.t0)
                 tel.inc("serve_prefill_chunks_total")
-                tel.span("prefill_chunk", t0, t1, request_id=st.req.id,
-                         step=self.steps, slot=slot, chunk=st.next,
-                         chunk_start=c0, chunk_len=C)
             st.next += 1
             if st.next == len(st.starts):
                 produced += self._commit_chunked(slot, st, h)
@@ -786,8 +787,7 @@ class Server:
         if self._prof is not None:
             self._prof.ensure_costed(cm_name, self._chunk_commit, cm_args)
         t0c = tel.now() if tel.enabled else 0.0
-        with self._annot(cm_name):
-            tok, new_pool = self._chunk_commit(*cm_args)
+        tok, new_pool = self._chunk_commit(*cm_args)
         self.pool.install_prefill(slot, new_pool, st.L)
         if tel.enabled:
             jax.block_until_ready(tok)
@@ -844,43 +844,50 @@ class Server:
 
     def _decode_once(self) -> int:
         tel = self.telemetry
-        step_fn, ds_args = self._decode_call()
+        attrs = {}
+        with tel.scope("decode_args", step=self.steps):
+            step_fn, ds_args = self._decode_call()
+            if tel.enabled:
+                # what the KV read covers, from the table actually passed
+                kv_read, kv_live = (
+                    self.pool.kv_read_counts(ds_args[-1]) if self._paged
+                    else self.pool.kv_read_counts())
+                n_active = self.pool.n_active
+                attrs = dict(n_active=n_active,
+                             batch_fill=n_active / self.pool.num_slots,
+                             kv_read=kv_read, kv_live=kv_live)
         if self._prof is not None:
             self._prof.ensure_costed("decode_step", step_fn, ds_args)
-        if tel.enabled:
-            n_active = self.pool.n_active
-            t0 = tel.now()
-        with self._annot("decode_step"):
+        with tel.scope("decode_step", step=self.steps, **attrs) as ds:
             nxt, self.pool.caches = step_fn(*ds_args)
+            if tel.enabled:
+                # fence at the dispatch boundary (the np.asarray below
+                # would sync anyway; the explicit fence makes the timed
+                # quantity "dispatch to completion", never a lazy transfer)
+                jax.block_until_ready(nxt)
         if tel.enabled:
-            # fence at the dispatch boundary (the np.asarray below would
-            # sync anyway; the explicit fence makes the timed quantity
-            # "dispatch to completion", never a lazy transfer)
-            jax.block_until_ready(nxt)
-            t1 = tel.now()
-            fill = n_active / self.pool.num_slots
+            dt = ds.t1 - ds.t0
             if self._prof is not None:
-                self._prof.observe("decode_step", t1 - t0)
-            tel.observe("serve_decode_step_seconds", t1 - t0)
-            tel.observe("serve_batch_fill", fill)
+                self._prof.observe("decode_step", dt)
+            tel.observe("serve_decode_step_seconds", dt)
+            tel.observe("serve_batch_fill", attrs["batch_fill"])
             tel.inc("serve_decode_steps_total")
-            tel.span("decode_step", t0, t1, step=self.steps,
-                     n_active=n_active, batch_fill=fill)
         nxt = np.asarray(nxt)
         produced = 0
-        for slot, req in list(self.scheduler.running.items()):
-            if slot in self._chunking:
-                continue    # mid-chunk: masked idle row, no token yet
-            t = int(nxt[slot])
-            self._emit(req, t)
-            produced += 1
-            self.pool.advance(slot)
-            if self.scheduler.should_retire(req):
-                self._retire(req, slot,
-                             "budget" if len(req.tokens) >= req.max_new
-                             else "eos")
-            elif self.pool.room(slot) <= 0:
-                self._retire(req, slot, "cache_full")
-            else:
-                self._cur_tok[slot] = t
+        with tel.scope("emit", step=self.steps):
+            for slot, req in list(self.scheduler.running.items()):
+                if slot in self._chunking:
+                    continue    # mid-chunk: masked idle row, no token yet
+                t = int(nxt[slot])
+                self._emit(req, t)
+                produced += 1
+                self.pool.advance(slot)
+                if self.scheduler.should_retire(req):
+                    self._retire(req, slot,
+                                 "budget" if len(req.tokens) >= req.max_new
+                                 else "eos")
+                elif self.pool.room(slot) <= 0:
+                    self._retire(req, slot, "cache_full")
+                else:
+                    self._cur_tok[slot] = t
         return produced

@@ -23,6 +23,12 @@ Two recorders, one contract:
   timing work behind ``if telemetry.enabled`` so the no-op recorder
   costs one attribute check per step and zero fences.
 
+Spans that start "now" are opened with ``Telemetry.scope(name, **attrs)``:
+one context manager that is both a ``jax.profiler.TraceAnnotation``
+named ``serve/<name>`` (so a device trace shows the host's part of each
+step by name) and a Tracer span on ``Telemetry.now`` at the same
+boundaries.  On ``NOOP`` it returns one shared null context.
+
 Metric families are declared once in ``METRIC_FAMILIES`` (the single
 source of truth mirrored by docs/observability.md); first use
 auto-registers the metric with its documented type/buckets.  Exposition:
@@ -38,6 +44,7 @@ actual K/V rows (the Server's ``kv_probe_every`` hook).
 
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from bisect import bisect_left, insort
@@ -479,6 +486,14 @@ class Telemetry:
         self.tracer.span(name, t0, t1, request_id=request_id, step=step,
                          **attrs)
 
+    def scope(self, name, *, request_id=None, step=None, **attrs):
+        """Context manager around work that starts now: a profiler
+        annotation ``serve/<name>`` carrying `attrs`, and on exit a
+        Tracer span `name` over the same stretch on ``now``.  The body
+        reads ``t0`` and may add to ``attrs``; ``t1`` is set on exit.
+        Spans that started in the past go through ``span``."""
+        return _Scope(self, name, request_id, step, attrs)
+
     def event(self, name, t, *, request_id=None, step=None, **attrs):
         self.tracer.event(name, t, request_id=request_id, step=step, **attrs)
 
@@ -494,8 +509,47 @@ class Telemetry:
             self.tracer.write_jsonl(trace_out)
 
 
+class _Scope:
+    """One open ``Telemetry.scope``."""
+
+    __slots__ = ("_tel", "_annotation", "name", "request_id", "step",
+                 "attrs", "t0", "t1")
+
+    def __init__(self, tel, name, request_id, step, attrs):
+        self._tel = tel
+        self.name = name
+        self.request_id = request_id
+        self.step = step
+        self.attrs = attrs
+        self.t0 = self.t1 = None
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(f"serve/{self.name}", **self.attrs)
+        self._annotation.__enter__()
+        self.t0 = self._tel.now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = self._tel.now()
+        self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._tel.span(self.name, self.t0, self.t1,
+                           request_id=self.request_id, step=self.step,
+                           **self.attrs)
+        return False
+
+
 def _noop(*_a, **_k) -> None:
     return None
+
+
+_NULL_SCOPE = contextlib.nullcontext()
+
+
+def _null_scope(*_a, **_k):
+    return _NULL_SCOPE
 
 
 class NoopTelemetry:
@@ -513,6 +567,8 @@ class NoopTelemetry:
 
     inc = set_gauge = observe = span = event = staticmethod(_noop)
     reset = write = staticmethod(_noop)
+    #: no annotation, no clock read, no span: the same null context always
+    scope = staticmethod(_null_scope)
 
 
 #: the shared default recorder — ``telemetry=`` kwargs point here

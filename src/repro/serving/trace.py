@@ -25,18 +25,22 @@ Request lifecycle names (docs/observability.md#span-schema):
     queue_wait    span  — submit to admission; attrs.steps = virtual wait
     prefill_chunk span  — ONE chunk of a chunked admission prefill;
                           attrs: slot, chunk (0-based index, required
-                          >= 0), chunk_start, chunk_len
+                          >= 0), chunk_start, chunk_len, padded_len
     prefill       span  — admission prefill dispatch to fence (chunked
                           admissions emit it at commit, after their
                           prefill_chunk spans); attrs: slot, prompt_len,
-                          padded_len (the static Engine's batched
-                          prefill carries a null request_id)
+                          padded_len (the compile bucket; the static
+                          Engine's batched prefill carries a null
+                          request_id)
     token         event — one emitted token; attrs.first marks the TTFT
                           edge (only first/last tokens are traced by
                           default — the full ITL distribution lives in
                           the serve_itl_seconds histogram)
-    decode_step   span  — one batched decode step; request_id null;
-                          attrs: n_active, batch_fill
+    decode_step   span  — one batched decode step, dispatch to fence;
+                          request_id null; attrs: n_active, batch_fill,
+                          kv_read (positions the step's KV read covers),
+                          kv_live (positions holding tokens, sum of
+                          pos + 1 over the active rows)
     preempt       event — request evicted from its slot by a
                           higher-priority admission; attrs: slot, by
                           (preemptor id), n_tokens
@@ -52,6 +56,20 @@ Request lifecycle names (docs/observability.md#span-schema):
                           private suffix; retires precede the retire
                           event so the lifecycle stays closed)
     retire        event — request finished; attrs: n_tokens, reason
+
+Server-step names (batched: request_id null, on the engine track), the
+host's part of ``Server.step`` around the spans above:
+
+    step          span  — one whole ``Server.step`` call; attrs.produced
+    admit         span  — admission, its prefills, spills and restores
+    decode_args   span  — the decode step's host arrays, page table
+                          upload and key split
+    emit          span  — the token / advance / retire loop after the
+                          decode step's tokens reach the host
+
+Spans that start "now" are written by ``Telemetry.scope``, which also
+opens a ``jax.profiler.TraceAnnotation`` named ``serve/<name>``, so a
+device trace shows them on the host plane on the profiler's clock.
 
 ``validate_events`` checks structure AND lifecycle ordering per request:
 exactly one submit, retire after submit, retired requests prefilled, and
@@ -82,7 +100,9 @@ from pathlib import Path
 TRACE_VERSION = 2
 
 SPAN_NAMES = {"queue_wait", "prefill", "prefill_chunk", "decode_step",
-              "spill", "restore"}
+              "spill", "restore", "step", "admit", "decode_args", "emit"}
+#: spans of batched server work: request_id must be null
+BATCHED_SPANS = {"decode_step", "step", "admit", "decode_args", "emit"}
 #: ``page_alloc`` / ``page_release`` are emitted by --paged serving only
 #: (serving/pages.py): page_alloc carries n_pages (fresh) + n_shared (COW
 #: forks) per admission/resume; page_release carries n_pages + reason and
@@ -210,12 +230,13 @@ def validate_events(events) -> dict:
         if not isinstance(ev["attrs"], dict):
             _fail(i, f"{name!r} attrs must be an object")
         rid = ev["request_id"]
-        if name == "decode_step":
+        if kind == "span" and name in BATCHED_SPANS:
             if rid is not None:
-                _fail(i, "decode_step is batched; request_id must be null")
-            if not (0 <= ev["attrs"].get("n_active", -1)):
-                _fail(i, "decode_step needs attrs.n_active >= 0")
-            n_steps += 1
+                _fail(i, f"{name} is batched; request_id must be null")
+            if name == "decode_step":
+                if not (0 <= ev["attrs"].get("n_active", -1)):
+                    _fail(i, "decode_step needs attrs.n_active >= 0")
+                n_steps += 1
             continue
         if name == "prefill" and rid is None:
             continue  # static Engine: one batched prefill, no request
@@ -359,7 +380,8 @@ def to_chrome_trace(events) -> dict:
     (the Perfetto / chrome://tracing format).
 
     Layout: one "engine" process holding the batched engine-step track
-    (decode_step spans and any request-less work), one "requests"
+    (the server-step spans step / admit / decode_args / decode_step /
+    emit, nested, and any request-less work), one "requests"
     process with one thread per request id (its queue_wait / prefill /
     spill / restore spans and submit / token / preempt / retire instant
     events).  Each preemption draws a flow arrow from the victim's

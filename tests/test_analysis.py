@@ -232,6 +232,30 @@ def test_rule_fires_on_corrupt_fixture(tmp_path, rule, fire, miss):
         f"{rule} must stay silent on its near-miss"
 
 
+def test_rl004_counts_scope_sites(tmp_path):
+    """Telemetry.scope(name) records a span: its name must be declared,
+    and a declared span emitted only by a scope is not dead."""
+    trace = """
+        SPAN_NAMES = {"prefill", "step"}
+        EVENT_NAMES = {"submit"}
+    """
+    emit = """
+        def go(tel, t0, t1):
+            tel.span("prefill", t0, t1)
+            tel.event("submit", t0)
+            with tel.scope("step"):
+                pass
+    """
+    assert "RL004" not in _rules_fired(_mini_repo(
+        tmp_path / "ok", {"trace.py": trace, "emit.py": emit}))
+    bad = emit + """
+            with tel.scope("undeclared"):
+                pass
+    """
+    assert "RL004" in _rules_fired(_mini_repo(
+        tmp_path / "bad", {"trace.py": trace, "emit.py": bad}))
+
+
 @pytest.mark.parametrize("rule,fire,_", MATRIX, ids=[m[0] for m in MATRIX])
 def test_cli_exits_1_on_corrupt_fixture(tmp_path, rule, fire, _):
     root = _mini_repo(tmp_path, fire)

@@ -50,7 +50,7 @@ from repro.serving import (
     trace_stats,
     validate_events,
 )
-from repro.serving.profiler import ProgramCost, null_annotation
+from repro.serving.profiler import ProgramCost
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))  # benchmarks/ is a repo-root package
@@ -127,8 +127,7 @@ def test_profiler_costs_attributes_and_exports():
     # idempotent: the cost cache returns the same object, no recompile
     assert sess.ensure_costed("dot[64]", f, args) is pc
 
-    with sess.annotation("dot[64]"):
-        jax.block_until_ready(f(*args))
+    jax.block_until_ready(f(*args))
     sess.observe("dot[64]", 1e-3)
     txt = reg.prometheus_text()
     for fam in ("profile_program_flops", "profile_program_hbm_bytes",
@@ -154,8 +153,14 @@ def test_profiler_roofline_math_and_null_annotation():
     assert pc.roofline_seconds(1e12, 1e12) == pytest.approx(2e-3)
     # memory-bound when bandwidth is the binding term
     assert pc.roofline_seconds(1e15, 1e9) == pytest.approx(0.1)
-    with null_annotation("anything"):
-        pass
+    # naming dispatches moved to Telemetry.scope: the profiler session
+    # has no annotation hook, and the no-op recorder's scope is the one
+    # shared null context the hook's stand-in used to be
+    sess = StepProfiler(peak_flops=1e12, hbm_bw=1e12).session(
+        MetricsRegistry())
+    assert not hasattr(sess, "annotation")
+    with NOOP.scope("anything") as sc:
+        assert sc is None
     assert NOOP.profiler is None
 
 

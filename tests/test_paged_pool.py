@@ -283,6 +283,30 @@ def test_admit_pages_write_mask_protects_shared_pages():
     assert not mask2[3:].any(), "bucket padding pages go to trash"
 
 
+def test_kv_read_counts_on_known_lengths():
+    """kv_read is what the decode step's KV read covers (the page table
+    passed to it, or the slot pool's whole rows); kv_live is the sum of
+    pos + 1 over the decoding rows."""
+    from repro.serving.kvcache import SlotKVCache
+
+    for pool in (SlotKVCache(CFG, 4, 32),
+                 PagedKVPool(CFG, 4, 32, page_size=8)):
+        a, b, c = pool.alloc(), pool.alloc(), pool.alloc()
+        pool.next_pos[a] = 5            # decodes at 5: positions 0..5 live
+        pool.next_pos[b] = 31           # the last position of its row
+        # c is bound but has no committed rows yet (mid-chunk): pos -1
+        if isinstance(pool, PagedKVPool):
+            table = np.zeros((4, pool.pages_per_seq), np.int32)
+            assert pool.kv_read_counts(table) == (4 * 4 * 8, 6 + 32)
+            # a narrower table passed to the step reads fewer positions
+            assert pool.kv_read_counts(table[:, :2]) == (4 * 2 * 8, 6 + 32)
+        else:
+            assert pool.kv_read_counts() == (4 * 32, 6 + 32)
+        pool.free(b)
+        assert pool.live_positions() == 6
+        assert pool.next_pos[c] == -1
+
+
 def test_gather_pages_reconstructs_table_order():
     leaf = jnp.arange(6 * 4 * 3).reshape(6, 4, 3).astype(jnp.float32)
     page_map = jnp.asarray([[3, 1, 0], [2, 2, 5]], jnp.int32)

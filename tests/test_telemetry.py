@@ -220,6 +220,39 @@ def test_noop_recorder_is_free():
     assert NOOP.registry is None and NOOP.tracer is None
 
 
+def test_noop_scope_is_shared_and_records_nothing():
+    """NOOP.scope hands back one shared null context whatever it is
+    asked for: no annotation, no clock read, no span."""
+    s = NOOP.scope("step", step=3)
+    assert s is NOOP.scope("decode_step", n_active=2) is NOOP.scope("emit")
+    with s as sc:
+        assert sc is None
+    with s:     # reusable, and nested in itself
+        with s:
+            pass
+    assert NOOP.tracer is None
+
+
+def test_scope_records_a_span_with_body_attrs():
+    """A live scope writes one Tracer span over its body on Telemetry.now,
+    with the attrs given at open and those the body added."""
+    tel = Telemetry()
+    with tel.scope("step", step=7, a=1) as sc:
+        t_in = tel.now()
+        sc.attrs["produced"] = 4
+    assert sc.t0 <= t_in <= sc.t1
+    (ev,) = tel.tracer.events
+    assert (ev["kind"], ev["name"], ev["step"], ev["request_id"]) \
+        == ("span", "step", 7, None)
+    assert (ev["t0"], ev["t1"]) == (sc.t0, sc.t1)
+    assert ev["attrs"] == {"a": 1, "produced": 4}
+    # a body that raises records no span
+    with pytest.raises(RuntimeError):
+        with tel.scope("emit"):
+            raise RuntimeError("boom")
+    assert len(tel.tracer.events) == 1
+
+
 def test_greedy_tokens_identical_with_telemetry_on_vs_off(params):
     """THE acceptance criterion: a recording Telemetry must not change
     greedy outputs — all instrumentation is host-side, outside the
@@ -243,6 +276,23 @@ def test_greedy_tokens_identical_with_telemetry_on_vs_off(params):
     assert d["serve_tokens_total"][""] == sum(budgets)
     assert d["serve_batch_fill"][""]["count"] > 0
     assert d["serve_prefill_pad_frac"][""]["count"] == len(lens)
+
+    # the paged kv4 server, whose step runs under every scope
+    cfg4 = CFG.with_kv_quant(4)
+    p4 = lm.init_params(jax.random.PRNGKey(0), cfg4)
+
+    def serve_paged(telemetry):
+        srv = Server(p4, cfg4, num_slots=2, max_seq_len=32, paged=True,
+                     page_size=8, telemetry=telemetry)
+        ids = [srv.submit(p, m, arrival_time=1.5 * i)
+               for i, (p, m) in enumerate(zip(prompts, budgets))]
+        res = srv.run_until_drained()
+        return [res[r] for r in ids]
+
+    tel_p = Telemetry()
+    assert serve_paged(tel_p) == serve_paged(NOOP)
+    assert {e["name"] for e in tel_p.tracer.events} >= {
+        "step", "admit", "decode_args", "decode_step", "emit"}
 
     # static Engine: same contract
     ep = jnp.asarray(_prompts(3, 9, seed=50))
@@ -282,6 +332,47 @@ def test_live_trace_validates_and_counts(params, tmp_path):
     assert validate_jsonl(p)["events"] == stats["events"]
 
 
+def test_live_paged_trace_scopes_nest(params):
+    """A paged server's trace validates, every scope sits inside its
+    step, the step's device-bound children leave it a non-negative host
+    time, and each decode step carries its KV read counts."""
+    from repro.serving import to_chrome_trace
+
+    tel = Telemetry()
+    srv = Server(params, CFG, num_slots=2, max_seq_len=32, paged=True,
+                 page_size=8, telemetry=tel)
+    for i, L in enumerate([6, 9, 7]):
+        srv.submit(_prompts(1, L, seed=80 + i)[0], 4, arrival_time=0.5 * i)
+    srv.run_until_drained()
+    events = tel.tracer.events
+    assert validate_events(events)["decode_steps"] > 0
+    spans = [e for e in events if e["kind"] == "span"]
+    steps = [e for e in spans if e["name"] == "step"]
+    assert len(steps) == srv.steps
+    children = {"admit", "decode_args", "decode_step", "emit", "prefill"}
+    for e in spans:
+        if e["name"] not in children:
+            continue
+        owner = [s for s in steps if s["t0"] <= e["t0"] and e["t1"] <= s["t1"]]
+        assert len(owner) == 1, f"{e['name']} lies inside no single step"
+        if e["name"] != "prefill":
+            assert owner[0]["step"] == e["step"]
+    for s in steps:
+        inner = [e for e in spans if e["name"] in ("decode_step", "prefill")
+                 and s["t0"] <= e["t0"] and e["t1"] <= s["t1"]]
+        assert s["t1"] - s["t0"] - sum(e["t1"] - e["t0"] for e in inner) >= 0
+    for e in spans:
+        if e["name"] == "decode_step":
+            a = e["attrs"]
+            assert a["kv_read"] == 2 * 32    # num_slots x pages x page_size
+            assert 0 < a["kv_live"] <= a["kv_read"]
+    assert sum(s["attrs"]["produced"] for s in steps) == 3 * 4
+    # the server-step spans go on the engine track
+    engine = {ev["name"] for ev in to_chrome_trace(events)["traceEvents"]
+              if ev.get("ph") == "X" and ev["pid"] == 1}
+    assert engine == {"step", "admit", "decode_args", "decode_step", "emit"}
+
+
 def _ok_events():
     t = Telemetry()
     t.event("submit", 0.0, request_id=1, step=0)
@@ -315,6 +406,21 @@ def test_trace_validator_accepts_and_rejects():
     corrupt(lambda e: e.append(dict(e[3], t0=9.9)), "after retire")
     # a retired request must have prefilled
     corrupt(lambda e: e.pop(2), "without a prefill")
+
+
+def test_trace_validator_server_step_spans_are_batched():
+    """step / admit / decode_args / emit validate as batched spans with a
+    null request_id, like decode_step, and are refused with one."""
+    t = Telemetry()
+    t.span("step", 0.0, 1.0, step=0, produced=0)
+    for name in ("admit", "decode_args", "emit"):
+        t.span(name, 0.1, 0.2, step=0)
+    assert validate_events(t.tracer.events)["spans"] == 4
+    for name in ("step", "admit", "decode_args", "emit"):
+        bad = Telemetry()
+        bad.span(name, 0.0, 1.0, request_id=1, step=0)
+        with pytest.raises(ValueError, match=f"{name} is batched"):
+            validate_events(bad.tracer.events)
 
 
 def _ok_sla_events():
