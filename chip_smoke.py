@@ -12,7 +12,8 @@ buckets through the paged ``Server`` (4 slots, 32 new tokens each, a
 cold run and a warm rerun that must repeat it token for token); compare
 prefill logits of the fused Pallas path with the ``dequant_einsum``
 oracle on the same weights; check the served decode step calls both
-Pallas kernels (``tpu_custom_call``).
+Pallas kernels (``tpu_custom_call``); check the compiled KV dequant
+kernel gives its jnp oracle's rows bit for bit at both benchmark widths.
 
 ``--chips 4`` runs only the sharded path and its comparison: the same
 weights column-parallel on a 1x4 mesh through the ``Sharder`` and the
@@ -67,6 +68,10 @@ TOP1_MIN = 0.5
 #: get looser max/RMS bounds — still far below a wrong layout's ~1
 KV4_MAX_REL_TOL = 0.25
 KV4_RMS_REL_TOL = 0.1
+#: KV row widths checked bit for bit against the oracle: qwen2-7b (4 KV
+#: heads x 128) and deepseek-coder-33b (8 x 128), at both cache precisions;
+#: a row count that the kernel's tile does not divide
+KV_FEATS, KV_BITS_CHECKED, KV_ROWS = (512, 1024), (4, 8), 3000
 #: the four-chip teacher-forced comparison: prompts, prompt length, steps
 TF_BATCH, TF_LEN, TF_STEPS = 4, 384, 8
 
@@ -214,6 +219,35 @@ def peak_bytes(dev) -> int:
 # one chip
 
 
+def check_kv_dequant(seed: int) -> None:
+    """The compiled KV dequant kernel gives the oracle's bf16 rows bit for
+    bit: every word pattern, random block scales."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import kv_dequant as kd
+
+    rng = np.random.default_rng(seed)
+    for feat in KV_FEATS:
+        for bits in KV_BITS_CHECKED:
+            spec = kd.KVQuantSpec(bits=bits, block_size=64, dtype_name="float")
+            _, n_blocks, n_words = kd.kv_layout(spec, feat)
+            packed = jnp.asarray(rng.integers(0, 2**32, (KV_ROWS, n_words),
+                                              dtype=np.uint32))
+            scales = jnp.asarray(rng.uniform(1e-3, 4.0, (KV_ROWS, n_blocks)),
+                                 jnp.bfloat16)
+            ker = kd.dequant_rows_pallas(packed, scales, spec, feat,
+                                         interpret=False)
+            ref = jax.jit(kd.dequant_rows_ref, static_argnums=(2, 3))(
+                packed, scales, spec, feat)
+            n_diff = int(jnp.sum(ker != ref))
+            print(f"kv{bits} dequant kernel vs oracle at feat {feat}: "
+                  f"{n_diff} of {ref.size} values differ", flush=True)
+            check(n_diff == 0, f"kv{bits} dequant kernel differs from the "
+                  f"oracle at feat {feat}")
+
+
 def run_one_chip(devs, seed: int, clock: CompileClock) -> None:
     import jax
     import jax.numpy as jnp
@@ -266,6 +300,7 @@ def run_one_chip(devs, seed: int, clock: CompileClock) -> None:
                    logits_fn("dequant_einsum")(params, toks),
                    f"prefill logits, fused Pallas vs dequant_einsum oracle "
                    f"({toks.shape[1]} tokens)")
+    check_kv_dequant(seed)
 
     n_tok = N_REQUESTS * MAX_NEW
     print(f"smoke numbers (one short run, not a benchmark): compile "

@@ -14,7 +14,17 @@ features, chunked into blocks along that feature dim:
     packed  uint32 [..., S_c, feat // cpw]   cpw = 32 // bits codes per word
     scales  bf16   [..., S_c, feat // bs]    per-block absmax constants
 
-``bs`` is ``kv_block_size`` clamped to the feature dim (tiny heads).  Only
+``bs`` is ``kv_block_size`` clamped to the feature dim (tiny heads).
+Words are PLANE-MAJOR: with ``W = feat // cpw`` words a row, code ``p``
+of word ``w`` (bits ``p*bits`` up) is feature ``p * W + w``.  Shift/mask
+plane ``p`` of a row's words is then features ``p*W .. p*W + W - 1`` in
+order, so the kernel unpacks by whole-vreg shifts and a lane concatenate
+of the planes, with no lane shuffles (an interleaved order, feature
+``w * cpw + p``, has to scatter every plane across the lanes: that
+relayout was ~85% of the kernel's vector ops).  ``pack_rows`` and
+``unpack_rows`` are the only code that knows the order; weights keep
+core/packing.py's interleaved order (kernels/qmatmul.py permutes x
+instead).  Only
 k in {4, 8} is supported: both pack exactly into 32-bit words, and they are
 the paper's serving-relevant precisions.  Quantile codebooks are excluded —
 the decode-step append-quantize is streaming and needs a static codebook.
@@ -29,10 +39,10 @@ lets models/sharding.py sequence-shard the packed leaves and call
 Three read paths, one semantics:
 
   * ``dequant_rows_ref``    — pure jnp (gather) oracle; CPU / tests.
-  * ``dequant_rows_pallas`` — Pallas TPU kernel: unpack (shift/mask) +
-    compare-select dequant over the 2**k codebook entries (same no-gather
-    trick as kernels/qmatmul.py) + block-scale multiply, one row tile per
-    grid step.  Streams k/16 of the bf16 cache bytes from HBM.
+  * ``dequant_rows_pallas`` — Pallas TPU kernel: unpack (shift/mask
+    planes) + a select tree over the 2**k codebook entries (no gathers,
+    as kernels/qmatmul.py) + block-scale multiply, one row tile per grid
+    step.  Streams k/16 of the bf16 cache bytes from HBM.
   * ``dequant_rows``        — dispatcher: the kernel on TPU, the oracle
     elsewhere (kernels/platform.py).
 """
@@ -120,8 +130,26 @@ def encode_rows(x: jnp.ndarray, spec: KVQuantSpec):
     # gather-free, as core/blockwise.encode
     codes = jnp.searchsorted(bounds, normed,
                              method="compare_all").astype(jnp.uint32)
-    packed = packing.pack(codes.reshape(x.shape[:-1] + (feat,)), spec.bits)
+    packed = pack_rows(codes.reshape(x.shape[:-1] + (feat,)), spec.bits)
     return packed, scales.astype(jnp.bfloat16)
+
+
+def pack_rows(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """Pack codes [..., feat] plane-major: code ``p`` of word ``w`` (bits
+    ``p*bits`` up) is feature ``p * W + w``, ``W = feat // cpw``."""
+    cpw = packing.codes_per_word(bits)
+    c = codes.astype(jnp.uint32).reshape(codes.shape[:-1] + (cpw, -1))
+    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * bits)[:, None]
+    # codes occupy disjoint bit ranges, so a sum equals the bitwise OR
+    return jnp.sum(c << shifts, axis=-2, dtype=jnp.uint32)
+
+
+def unpack_rows(words: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """Inverse of ``pack_rows``: words [..., W] -> codes uint32 [..., W*cpw]."""
+    cpw = packing.codes_per_word(bits)
+    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * bits)[:, None]
+    c = (words[..., None, :] >> shifts) & jnp.uint32((1 << bits) - 1)
+    return c.reshape(words.shape[:-1] + (cpw * words.shape[-1],))
 
 
 # --------------------------------------------------------------------------
@@ -132,43 +160,101 @@ def dequant_rows_ref(packed, scales, spec: KVQuantSpec, feat: int,
                      out_dtype=jnp.bfloat16):
     """Pure-jnp oracle: packed [..., W] + scales [..., NB] -> [..., feat]."""
     bs, n_blocks, _ = kv_layout(spec, feat)
-    codes = packing.unpack(packed, spec.bits, feat)
+    codes = unpack_rows(packed, spec.bits)
     vals = jnp.take(kv_codebook(spec), codes.astype(jnp.int32), axis=0)
     vals = vals.reshape(packed.shape[:-1] + (n_blocks, bs))
     vals = vals * scales[..., None].astype(jnp.float32)
     return vals.reshape(packed.shape[:-1] + (feat,)).astype(out_dtype)
 
 
-def _dequant_kernel(p_ref, s_ref, cb_ref, o_ref, *, bits, bs, feat, dtype_name):
-    """One row tile: unpack -> compare-select dequant -> scale multiply."""
+#: rows of one unrolled step of the kernel.  A step bounds the select
+#: tree's live values, which over a whole tile outgrow the scoped VMEM
+#: (kv8 at any tile, kv4 past 512 rows at feat 512); on a v5e 64 rows read
+#: fastest at feat 512 and within 4% of the fastest at feat 1024
+_STEP_ROWS = 64
+#: rows x features x codebook entries of one grid step: the unrolled
+#: select trees of a tile, so its code and its VMEM blocks (~6 MiB at kv4)
+#: stay bounded at any width; 2048 rows at feat 512, 1024 at feat 1024
+_TILE_WORK = 1 << 24
+
+
+def kv_tile_rows(spec: KVQuantSpec, feat: int) -> int:
+    """Rows of one grid step of ``dequant_rows_pallas``: a power of two,
+    at least 16, with rows x feat x 2**bits at most ``_TILE_WORK``.  Few,
+    long grid steps amortize the ~0.35 us each grid step costs."""
+    rows = _TILE_WORK // (feat * 2**spec.bits)
+    return max(16, 1 << (rows.bit_length() - 1))
+
+
+def _lookup(codes, cb_ref, bits: int):
+    """codebook[codes] for codes uint32 [r, n] -> f32 [r, n], with no
+    gather: a select tree on the code's bits, bit 0 at the leaves.  That
+    is 2**bits - 1 selects and ``bits`` bit tests a value, against 2**bits
+    compares and selects for a compare-select chain."""
+    bit = [(codes & jnp.uint32(1 << k)) != 0 for k in range(bits)]
+
+    def subtree(level, base):       # the entries base .. base + 2**level - 1
+        if level == 0:
+            return cb_ref[0, base]
+        half = 2 ** (level - 1)
+        return jnp.where(bit[level - 1], subtree(level - 1, base + half),
+                         subtree(level - 1, base))
+
+    return subtree(bits, 0)
+
+
+def _expand_scales(s, bs: int):
+    """Block scales s bf16 [r, NB] -> per-feature f32 [r, NB * bs] on the
+    MXU, which this kernel leaves idle: s @ E with E[b, f] = (f // bs ==
+    b) has one nonzero product per output, so it is exact."""
+    n_blocks = s.shape[1]
+    f = jax.lax.broadcasted_iota(jnp.int32, (n_blocks, n_blocks * bs), 1)
+    b = jax.lax.broadcasted_iota(jnp.int32, (n_blocks, n_blocks * bs), 0)
+    return jnp.dot(s, (f // bs == b).astype(s.dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def _dequant_kernel(p_ref, s_ref, cb_ref, o_ref, *, bits, bs, dtype_name,
+                    step_rows):
+    """One row tile in unrolled steps of ``step_rows`` rows: unpack ->
+    dequant -> scale multiply.
+
+    Nothing crosses lanes: shift/mask plane ``p`` of the words [r, W]
+    holds features ``p*W .. p*W + W - 1`` in order (plane-major words),
+    so the planes concatenate along the lanes into the [r, feat] codes."""
     cpw = 32 // bits
-    words = p_ref[...]                                   # [tr, feat//cpw]
-    shifts = jnp.arange(cpw, dtype=jnp.uint32) * bits
     mask = jnp.uint32((1 << bits) - 1)
-    codes = (words[:, :, None] >> shifts[None, None, :]) & mask
-    codes = codes.reshape(words.shape[0], feat)
-    if dtype_name == "int":
-        half = float(2 ** (bits - 1) - 1)
-        # via int32: Mosaic has no uint32 -> f32 cast (codes < 2**bits)
-        vals = jnp.clip(codes.astype(jnp.int32).astype(jnp.float32) - half,
-                        -half, half) / half
-    else:
-        vals = jnp.zeros(codes.shape, jnp.float32)
-        for j in range(2**bits):                         # vectorized selects
-            vals = jnp.where(codes == j, cb_ref[0, j], vals)
-    scales = jnp.repeat(s_ref[...].astype(jnp.float32), bs, axis=1)
-    o_ref[...] = (vals * scales).astype(o_ref.dtype)
+    for r0 in range(0, p_ref.shape[0], step_rows):
+        rows = pl.ds(r0, step_rows)
+        words = p_ref[rows, :]
+        codes = jnp.concatenate(
+            [(words >> jnp.uint32(p * bits)) & mask for p in range(cpw)],
+            axis=1)
+        if dtype_name == "int":
+            half = float(2 ** (bits - 1) - 1)
+            # via int32: Mosaic has no uint32 -> f32 cast (codes < 2**bits)
+            vals = jnp.clip(
+                codes.astype(jnp.int32).astype(jnp.float32) - half,
+                -half, half) / half
+        else:
+            vals = _lookup(codes, cb_ref, bits)
+        o_ref[rows, :] = (vals * _expand_scales(s_ref[rows, :], bs)
+                          ).astype(o_ref.dtype)
 
 
 def dequant_rows_pallas(packed, scales, spec: KVQuantSpec, feat: int, *,
-                        tile_rows: int = 128, interpret: bool | None = None,
+                        tile_rows: int | None = None,
+                        interpret: bool | None = None,
                         out_dtype=jnp.bfloat16):
-    """Pallas dequant of flattened rows: packed [R, W], scales [R, NB] ->
-    [R, feat].  Rows are padded up to a tile multiple and sliced back.
-    ``interpret`` None: compiled on TPU, interpreted elsewhere."""
+    """Pallas dequant of flattened rows: packed [R, W], scales bf16 [R, NB]
+    -> [R, feat].  ``tile_rows`` rows a grid step (None: ``kv_tile_rows``);
+    rows are padded up to a tile multiple and sliced back.  ``interpret``
+    None: compiled on TPU, interpreted elsewhere."""
     bs, n_blocks, n_words = kv_layout(spec, feat)
     R = packed.shape[0]
-    tr = min(tile_rows, max(R, 1))
+    if tile_rows is None:
+        tile_rows = kv_tile_rows(spec, feat)
+    tr = min(tile_rows, -(-max(R, 1) // 16) * 16)
     n_tiles = -(-R // tr)
     pad = n_tiles * tr - R
     if pad:
@@ -178,8 +264,8 @@ def dequant_rows_pallas(packed, scales, spec: KVQuantSpec, feat: int, *,
             [scales, jnp.zeros((pad, n_blocks), scales.dtype)])
     cb2 = kv_codebook(spec).reshape(1, -1)
     kernel = functools.partial(
-        _dequant_kernel, bits=spec.bits, bs=bs, feat=feat,
-        dtype_name=spec.dtype_name,
+        _dequant_kernel, bits=spec.bits, bs=bs, dtype_name=spec.dtype_name,
+        step_rows=math.gcd(_STEP_ROWS, tr),
     )
     out = pl.pallas_call(
         kernel,

@@ -4,8 +4,8 @@ branches of models/attention.py + the serving slot pool over packed leaves).
 Three layers of guarantees:
 
   (a) the codec: encode -> dequant round-trips within the data type's
-      expected error, and the Pallas compare-select kernel (interpret
-      mode) matches the jnp oracle exactly;
+      expected error, the words are plane-major, and the Pallas kernel
+      (interpret mode) matches the jnp oracle exactly;
   (b) the model: decode with a k-bit cache stays within a stated
       per-token logit tolerance of the bf16-cache oracle (teacher-forced,
       so the check is deterministic), and the static Engine and the
@@ -78,6 +78,58 @@ def test_pallas_kernel_matches_oracle(bits, dtype):
     ker = kd.dequant_rows_pallas(packed, scales, spec, 96,
                                  interpret=True, tile_rows=16)
     assert bool(jnp.all(ref == ker))  # same math, bit-for-bit
+
+
+def test_words_are_plane_major():
+    """Code p of word w in a token row is feature p * W + w (W words a
+    row): each shift/mask plane of the words is W consecutive features."""
+    bits, feat = 4, 16
+    W = feat // 8
+    codes = jnp.arange(feat, dtype=jnp.uint32)[None, :]   # feature f -> f
+    by_hand = [sum((p * W + w) << (4 * p) for p in range(8))
+               for w in range(W)]
+    words = kd.pack_rows(codes, bits)
+    assert words.tolist() == [by_hand]
+    assert bool(jnp.all(kd.unpack_rows(words, bits) == codes))
+    spec = kd.KVQuantSpec(bits=bits, block_size=feat)
+    vals = kd.dequant_rows_ref(jnp.asarray([by_hand], jnp.uint32),
+                               jnp.ones((1, 1), jnp.bfloat16), spec, feat,
+                               out_dtype=jnp.float32)
+    assert bool(jnp.all(vals[0] == kd.kv_codebook(spec)))
+
+
+@pytest.mark.parametrize("bits,dtype", [(4, "float"), (8, "int"),
+                                        (4, "dynamic")])
+def test_roundtrip_same_values_as_interleaved_words(bits, dtype):
+    """The word order is storage only: the values a token row decodes to
+    are those of the interleaved order (core/packing) on the same codes."""
+    from repro.core import packing
+
+    spec = kd.KVQuantSpec(bits=bits, block_size=32, dtype_name=dtype)
+    x = jax.random.normal(jax.random.PRNGKey(5), (9, 96)) * 0.7
+    packed, scales = kd.encode_rows(x, spec)
+    codes = kd.unpack_rows(packed, bits)
+    inter = packing.unpack(packing.pack(codes, bits), bits, 96)
+    vals = jnp.take(kd.kv_codebook(spec), inter.astype(jnp.int32), axis=0)
+    old = (vals.reshape(9, 3, 32)
+           * scales[..., None].astype(jnp.float32)).reshape(9, 96)
+    new = kd.dequant_rows_ref(packed, scales, spec, 96)
+    assert bool(jnp.all(new == old.astype(jnp.bfloat16)))
+
+
+@pytest.mark.parametrize("dtype", ["float", "int"])
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("feat", [512, 1024])
+def test_pallas_kernel_matches_oracle_at_serving_widths(feat, bits, dtype):
+    """qwen2-7b (4 KV heads x 128) and deepseek-coder-33b (8 x 128) rows,
+    at the kernel's own tile rows and a row count no tile divides."""
+    spec = kd.KVQuantSpec(bits=bits, block_size=64, dtype_name=dtype)
+    rows = kd.kv_tile_rows(spec, feat) + 40
+    x = jax.random.normal(jax.random.PRNGKey(feat + bits), (rows, feat))
+    packed, scales = kd.encode_rows(x, spec)
+    ref = kd.dequant_rows_ref(packed, scales, spec, feat)
+    ker = kd.dequant_rows_pallas(packed, scales, spec, feat, interpret=True)
+    assert bool(jnp.all(ref == ker))
 
 
 def test_block_size_clamps_to_feature_dim():

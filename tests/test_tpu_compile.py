@@ -9,8 +9,9 @@ compile — without a chip — for a described ``v5e:2x2`` topology:
   (3584 -> 152064) shapes, at 4-bit float (the paper's recommended
   weights) and 8-bit int, for a decode step (M = 8 slots) and a prefill
   bucket (M = 512);
-* ``dequant_rows_pallas`` (the packed KV-cache read) at qwen2-7b's
-  feature width (4 KV heads x 128 = 512) for kv4 and kv8.
+* ``dequant_rows_pallas`` (the packed KV-cache read), at the tile rows
+  it chooses, at qwen2-7b's feature width (4 KV heads x 128 = 512) and
+  deepseek-coder-33b's (8 x 128 = 1024), for kv4 and kv8.
 
 Nothing runs: a pass says the chip's compiler takes the kernel, not that
 it is fast or right on the chip (chip_smoke.py checks that).  The
@@ -88,9 +89,14 @@ def test_qmatmul_compiles_for_v5e(one_chip, name, K, N, bits, dtype, M):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("bits", [4, 8])
-def test_kv_dequant_compiles_for_v5e(one_chip, bits):
-    feat = 4 * 128            # qwen2-7b: 4 KV heads x head_dim 128
+#: (kv bits, row width): qwen2-7b's 4 KV heads x head_dim 128, and
+#: deepseek-coder-33b's 8 x 128
+KV_CASES = [(4, 4 * 128), (8, 4 * 128), (4, 8 * 128), (8, 8 * 128)]
+
+
+@pytest.mark.parametrize("bits,feat", KV_CASES,
+                         ids=["4", "8", "4-feat1024", "8-feat1024"])
+def test_kv_dequant_compiles_for_v5e(one_chip, bits, feat):
     rows = 4 * 1056           # 4 slots x a 1056-token cache
     spec = kd.KVQuantSpec(bits=bits, block_size=64, dtype_name="float")
     _, n_blocks, n_words = kd.kv_layout(spec, feat)
