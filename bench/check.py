@@ -2,7 +2,8 @@
 
 Once the window has closed, a sample of the requests the timed run
 served is drawn from the seed (``sample``), the longest always in it.  The
-reference runs over each prompt followed by its served tokens, and each
+plain reference of the configuration's family (``bench/families/``) runs
+over each prompt followed by its served tokens, and each
 served token is read by how far its reference logit lies below the
 reference's best logit at that position: 0 where the served token is the
 reference's greedy choice.  A cell compares the widest such gap, or, where
@@ -13,8 +14,6 @@ that does not separate sound runs from the control, the mean gap
 from __future__ import annotations
 
 import numpy as np
-
-from bench import reference
 
 
 def sample(logs, seed: int, check: dict) -> list:
@@ -52,28 +51,28 @@ def sequences(picked) -> list:
              len(g.prompt), len(g.tokens)) for g in picked]
 
 
-def gaps(weights, spec, picked, geometry: dict):
+def gaps(family, weights, spec, picked, geometry: dict):
     """Per picked request, the reference gap of each served token."""
     if not picked:
         return []
     seqs = sequences(picked)
     want = [np.asarray(g.tokens, np.int32)[:, None] for g in picked]
-    res = reference.score(weights, spec, seqs, want, **geometry)
+    res = family.score(weights, spec, seqs, want, **geometry)
     return [best - got[:, 0] for _, best, got in res]
 
 
-def with_control(weights, spec, picked, geometry: dict):
+def with_control(family, weights, spec, picked, geometry: dict):
     """The program's gaps and the control's on the same sequences.  The
     control is the reference computed one precision lower (fp8): at each
     position it chooses the token it ranks first, and the reference reads
     the gap of that choice."""
     seqs = sequences(picked)
     dummy = [np.zeros((len(g.tokens), 1), np.int32) for g in picked]
-    low = reference.score(weights, spec, seqs, dummy, precision="fp8",
-                          **geometry)
+    low = family.score(weights, spec, seqs, dummy, precision="fp8",
+                       **geometry)
     want = [np.stack([np.asarray(g.tokens, np.int32), arg.astype(np.int32)], 1)
             for g, (arg, _, _) in zip(picked, low)]
-    res = reference.score(weights, spec, seqs, want, **geometry)
+    res = family.score(weights, spec, seqs, want, **geometry)
     return ([best - got[:, 0] for _, best, got in res],
             [best - got[:, 1] for _, best, got in res])
 

@@ -50,12 +50,13 @@ def main() -> int:
         row = {"seed": seed, "sampled": len(picked),
                "served_tokens": sum(len(g.tokens) for g in picked)}
         if seed in ctrl:
-            prog, low = check.with_control(weights, ctx.spec, picked, geo)
+            prog, low = check.with_control(cell.family, weights, ctx.spec,
+                                           picked, geo)
             row["control"] = check.readings(low)
             row["control_correct"] = check.decide(
                 row["control"], cell.check["limits"])[0]
         else:
-            prog = check.gaps(weights, ctx.spec, picked, geo)
+            prog = check.gaps(cell.family, weights, ctx.spec, picked, geo)
         row["program"] = check.readings(prog)
         row["program_correct"] = check.decide(
             row["program"], cell.check["limits"])[0]

@@ -3,7 +3,10 @@
 A cell names a configuration and a traffic mix; everything that belongs
 to one of them sits in its own file, found by name under ``bench/``:
 
-    configs/<config>.json    the model as run (model.py)
+    configs/<config>.json    the model as run
+    families/<family>.py     its spec, weights, adaptor to the program and
+                             plain reference; the configuration's
+                             "bench_family", dense where it has none
     traffic/<mix>.json       arrivals, lengths, pool and run phases
     checks/<cell>.json       the correctness sample and its limit
     metrics/<metric>.py      read(ctx) -> value or None, per metric
@@ -30,6 +33,7 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from pathlib import Path
 
 import numpy as np
@@ -59,6 +63,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: types.ModuleType
     mix: dict
     check: dict
     end_to_end: list
@@ -75,6 +80,7 @@ def load_cell(bench: dict, name: str, data: Path = BENCH) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     config = _load_json(data / "configs" / f"{w['config']}.json")
+    family = _load_module("families", config.get("bench_family", "dense"))
     mix = _load_json(data / "traffic" / f"{w['traffic']}.json")
     check = _load_json(data / "checks" / f"{name}.json")
 
@@ -89,8 +95,8 @@ def load_cell(bench: dict, name: str, data: Path = BENCH) -> Cell:
     costs = {p.stem: _load_module("costs", p.stem)
              for p in sorted((BENCH / "costs").glob("*.py"))
              if not p.stem.startswith("__")}
-    return Cell(name, w["chips"], config, mix, check, e2e, layer, readers,
-                costs)
+    return Cell(name, w["chips"], config, family, mix, check, e2e, layer,
+                readers, costs)
 
 
 def peaks_for(kind: str) -> dict:
@@ -174,6 +180,35 @@ def in_flight(mix: dict, server, seed: int, vocab: int, prompt_len: int) -> list
     return traffic.in_flight(mix, seed, n, vocab)
 
 
+def build_model(cell: Cell, seed: int):
+    """The cell's model through its family: (spec, the program's config,
+    the weights drawn from the seed)."""
+    fam = cell.family
+    spec = fam.spec_from_config(cell.config)
+    cfg = fam.program_config(cell.config, spec)
+    return spec, cfg, fam.make_weights(spec, seed)
+
+
+def start_server(cell: Cell, mix: dict, spec, cfg, weights, requests,
+                 seed: int, telemetry=None):
+    """The program's paged server for the cell's model, its pool sized by
+    `mix`, with every program the requests use warmed up and the pool's
+    steady population admitted.  Returns (server, that population)."""
+    from repro.serving.server import Server
+    from repro.serving.telemetry import NOOP
+
+    pool = mix["pool"]
+    server = Server(cell.family.program_params(weights, spec), cfg,
+                    num_slots=pool["num_slots"], max_seq_len=pool["max_seq_len"],
+                    page_size=pool["page_size"], paged=True, eos_id=None,
+                    telemetry=NOOP if telemetry is None else telemetry)
+    warm = _warm_up(server, requests, spec.vocab)
+    seeded = in_flight(mix, server, seed, spec.vocab,
+                       min(len(r.prompt) for r in requests))
+    _warm_up(server, seeded, spec.vocab, warm)
+    return server, seeded
+
+
 def serve(cell: Cell, seed: int, seconds: float, trace: bool, *,
           require_chip: bool = True, t_start: float | None = None):
     """Build the cell from the seed, warm it up and run its traffic; then
@@ -190,25 +225,15 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if require_chip:
         _enable_compile_cache()
 
-    from bench import loop, model, traffic
-    from repro.serving.server import Server
+    from bench import loop, traffic
     from repro.serving.telemetry import NOOP, Telemetry
 
-    spec = model.spec_from_config(cell.config)
-    cfg = model.program_config(cell.config, spec)
+    spec, cfg, weights = build_model(cell, seed)
     mix = cell.mix
-    weights = model.make_weights(spec, seed)
     requests = traffic.schedule(mix, seed, seconds, spec.vocab)
     tel = Telemetry() if trace else NOOP
-    pool = mix["pool"]
-    server = Server(model.program_params(weights, spec), cfg,
-                    num_slots=pool["num_slots"], max_seq_len=pool["max_seq_len"],
-                    page_size=pool["page_size"], paged=True, eos_id=None,
-                    telemetry=tel)
-    warm = _warm_up(server, requests, spec.vocab)
-    seeded = in_flight(mix, server, seed, spec.vocab,
-                       min(len(r.prompt) for r in requests))
-    _warm_up(server, seeded, spec.vocab, warm)
+    server, seeded = start_server(cell, mix, spec, cfg, weights, requests,
+                                  seed, tel)
     if trace:
         tel.reset()
 
@@ -289,7 +314,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
     picked = check.sample(logs, seed, cell.check)
-    got = check.readings(check.gaps(weights, ctx.spec, picked,
+    got = check.readings(check.gaps(cell.family, weights, ctx.spec, picked,
                                     cell.check["geometry"]))
     correct, compared = check.decide(got, cell.check["limits"])
 

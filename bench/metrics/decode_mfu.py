@@ -1,8 +1,8 @@
 """Model FLOPs of the traced decode steps over their device time at the
-chip's peak (%).  A step's FLOPs are 2 x the parameters every token
-multiplies x its active rows, plus attention: 4 x layers x query width
-x the live positions of its rows.  Device time is that of the decode
-program's runs in the trace (``jit_step_paged``)."""
+chip's peak (%).  A step's FLOPs are the count that the configuration's
+family gives for its active rows and their live positions
+(``spec.decode_flops``, ``bench/families/``).  Device time is that of the
+decode program's runs in the trace (``jit_step_paged``)."""
 
 
 def read(ctx):
@@ -10,9 +10,7 @@ def read(ctx):
     steps = ctx.decode_steps
     if not mod or not steps or mod["time_s"] <= 0:
         return None
-    s = ctx.spec
-    flops = [2.0 * s.matmul_params() * rows
-             + 4.0 * s.n_layers * s.q_dim * live for rows, live in steps]
+    flops = [ctx.spec.decode_flops(rows, live) for rows, live in steps]
     per_step = sum(flops) / len(flops)
     return 100.0 * per_step * mod["calls"] / (
         mod["time_s"] * ctx.peaks["bf16_flops_per_s"])

@@ -36,7 +36,7 @@ def main() -> int:
     p.add_argument("--seed", type=int, default=1)
     a = p.parse_args()
 
-    from bench import harness, loop, model, traffic
+    from bench import harness, loop, traffic
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = harness.load_cell(bench, a.workload)
@@ -46,25 +46,15 @@ def main() -> int:
         print("no TPU", file=sys.stderr)
         return 3
     harness._enable_compile_cache()
-    from repro.serving.server import Server
 
-    spec = model.spec_from_config(cell.config)
-    cfg = model.program_config(cell.config, spec)
-    weights = model.make_weights(spec, a.seed)
+    spec, cfg, weights = harness.build_model(cell, a.seed)
     for rate in (float(r) for r in a.rates.split(",")):
         mix = copy.deepcopy(cell.mix)
         mix["arrival"]["rate_per_s"] = rate
         reqs = traffic.schedule(mix, a.seed, a.seconds, spec.vocab)
-        pool = mix["pool"]
-        server = Server(model.program_params(weights, spec), cfg,
-                        num_slots=pool["num_slots"],
-                        max_seq_len=pool["max_seq_len"],
-                        page_size=pool["page_size"], paged=True, eos_id=None)
         t = time.perf_counter()
-        warmed = harness._warm_up(server, reqs, spec.vocab)
-        seeded = harness.in_flight(mix, server, a.seed, spec.vocab,
-                                   min(len(r.prompt) for r in reqs))
-        harness._warm_up(server, seeded, spec.vocab, warmed)
+        server, seeded = harness.start_server(cell, mix, spec, cfg, weights,
+                                              reqs, a.seed)
         warm = time.perf_counter() - t
         t_open = float(mix["warmup_s"])
         logs, _, ends = loop.drive(server, reqs, t_open=t_open,

@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, harness, model, reference
+from bench import check, harness
+from bench.families import dense
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -18,23 +19,23 @@ DATA = Path(__file__).resolve().parent / "data"
 @pytest.fixture(scope="module")
 def tiny():
     cfg = json.loads((DATA / "configs" / "tiny.json").read_text())
-    spec = model.spec_from_config(cfg)
-    return cfg, spec, model.make_weights(spec, 2**33 + 5)
+    spec = dense.spec_from_config(cfg)
+    return cfg, spec, dense.make_weights(spec, 2**33 + 5)
 
 
 def test_weights_decode_as_the_program_reads_them(tiny):
     from repro.core.qtensor import dequantize_tensor
 
     cfg, spec, w = tiny
-    params = model.program_params(w, spec)
-    cb = model.float_codebook(spec.w_bits, spec.w_ebits)
+    params = dense.program_params(w, spec)
+    cb = dense.float_codebook(spec.w_bits, spec.w_ebits)
     for name, (K, N) in spec.matrices().items():
         mod = "mixer" if name in ("wq", "wk", "wv", "wo") else "ffn"
         prog = dequantize_tensor(params["stack"][0][mod][name]["w"], jnp.float32)
         for i in range(spec.n_layers):
-            ref = reference.decode_matrix(w["layers"][name]["packed"][i],
-                                          w["layers"][name]["scales"][i], cb,
-                                          spec.w_bits, spec.w_block)
+            ref = dense.decode_matrix(w["layers"][name]["packed"][i],
+                                      w["layers"][name]["scales"][i], cb,
+                                      spec.w_bits, spec.w_block)
             np.testing.assert_array_equal(np.asarray(ref), np.asarray(prog[i]).T)
 
 
@@ -43,8 +44,8 @@ def test_program_tree_matches_its_own_builder(tiny):
     from repro.models.quantize import init_quantized_params
 
     cfg, spec, w = tiny
-    arch = model.program_config(cfg, spec)
-    ours = jax.tree.map(lambda a: (a.shape, a.dtype), model.program_params(w, spec))
+    arch = dense.program_config(cfg, spec)
+    ours = jax.tree.map(lambda a: (a.shape, a.dtype), dense.program_params(w, spec))
     theirs = jax.eval_shape(lambda: init_quantized_params(
         jax.random.PRNGKey(0), arch, QuantConfig(bits=4, dtype="float", block_size=64)))
     assert jax.tree.structure(ours) == jax.tree.structure(
@@ -59,9 +60,9 @@ def test_kv_format_as_the_program_stores_it(tiny):
     x = jax.random.normal(jax.random.PRNGKey(1), (64, spec.kv_dim)).astype(jnp.bfloat16)
     kvq = KVQuantSpec(bits=spec.kv_bits, block_size=spec.kv_block, dtype_name="float")
     prog = dequant_rows_ref(*encode_rows(x, kvq), kvq, spec.kv_dim, out_dtype=jnp.float32)
-    ref = reference.kv_round(x.astype(jnp.float32),
-                             model.float_codebook(spec.kv_bits, spec.kv_ebits),
-                             spec.kv_block)
+    ref = dense.kv_round(x.astype(jnp.float32),
+                         dense.float_codebook(spec.kv_bits, spec.kv_ebits),
+                         spec.kv_block)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(prog))
 
 
@@ -80,7 +81,7 @@ def test_served_tokens_are_the_reference_greedy_choices(served):
     assert len(picked) == 3
     assert len(picked[0].prompt) + len(picked[0].tokens) == max(
         len(g.prompt) + len(g.tokens) for g in picked)
-    got = check.readings(check.gaps(w, spec, picked, geo))
+    got = check.readings(check.gaps(dense, w, spec, picked, geo))
     assert got["tokens_compared"] == sum(len(g.tokens) for g in picked)
     # bf16 serving against an f32 reference: near-ties may flip, by little
     assert got["max_logit_gap"] < 0.2
@@ -89,7 +90,7 @@ def test_served_tokens_are_the_reference_greedy_choices(served):
 
 def test_control_one_precision_lower_reads_worse(served):
     spec, w, picked, chk = served
-    prog, low = check.with_control(w, spec, picked, chk["geometry"])
+    prog, low = check.with_control(dense, w, spec, picked, chk["geometry"])
     p, c = check.readings(prog), check.readings(low)
     assert c["max_logit_gap"] > 2 * p["max_logit_gap"]
     assert c["tokens_off_greedy"] > p["tokens_off_greedy"]

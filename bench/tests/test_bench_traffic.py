@@ -32,10 +32,14 @@ def test_seeds_put_the_same_work_in_each_phase(name):
     mix = _mix(name)
     a = traffic.schedule(mix, 1, 50, 1000)
     b = traffic.schedule(mix, 4_000_000_000, 50, 1000)
-    assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
     if mix["arrival"]["process"] == "backlog":
+        # one order of sizes for every seed; the seed draws the token ids
+        assert [len(r.prompt) for r in a] == [len(r.prompt) for r in b]
+        assert [r.max_new for r in a] == [r.max_new for r in b]
+        assert any(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
         phases = [(0.0, 1.0)]
     else:
+        assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
         phases = [(0.0, mix["warmup_s"]), (mix["warmup_s"], mix["warmup_s"] + 50)]
     for lo, hi in phases:
         pa = [r for r in a if lo <= r.due < hi]
@@ -74,6 +78,25 @@ def test_backlog_is_due_at_once():
     s = traffic.schedule(mix, 5, 50, 1000)
     assert len(s) == mix["arrival"]["count"]
     assert all(r.due == 0.0 for r in s)
+
+
+@pytest.mark.parametrize("key,base", [("prompt_len", 2), ("output_len", 3)])
+def test_backlog_admissions_spread_over_the_lengths(key, base):
+    # slots free one by one, so every stretch of the backlog's order is
+    # what some window admits: each prefix holds about as many lengths
+    # from every third of the law as the whole backlog does
+    mix = _mix("batch")
+    got = [len(r.prompt) if key == "prompt_len" else r.max_new
+           for r in traffic.schedule(mix, 9, 50, 1000)]
+    law = np.sort(traffic.lengths(mix[key], len(got)))
+    assert sorted(got) == law.tolist()
+    rank = np.argsort(traffic.spread_order(len(got), base))
+    assert sorted(rank.tolist()) == list(range(len(got)))
+    cuts = law[[len(got) // 3, 2 * len(got) // 3]]
+    for k in range(6, len(got) + 1):
+        third = np.searchsorted(cuts, got[:k], side="right")
+        for j in range(3):
+            assert abs(np.sum(third == j) - k / 3) <= 2
 
 
 @pytest.mark.parametrize("name", ["chat", "batch"])

@@ -7,7 +7,12 @@ the token ids.  An open-loop run has two phases, the warm-up and the
 measured window, and each holds its own fixed number of arrivals (the
 rate times its length) whose gaps add up to its length.  So every seed
 puts the same work in the window, and the spread between runs is the
-spread of the system, not of the draw.
+spread of the system, not of the draw.  A backlog's order is fixed as
+well: its requests are admitted one by one as slots free, so the order
+decides which prefills fall inside the window.  Its prompts and outputs
+take their quantiles in van der Corput order (base 2 and base 3), so
+every stretch of admissions spreads over the mix's lengths, and the seed
+draws only the token ids.
 
 A mix is either open loop (``arrival.process`` "gamma": Gamma-distributed
 gaps with the given mean rate and coefficient of variation, bursty when
@@ -42,6 +47,19 @@ class Request:
 
 def _quantiles(n: int) -> np.ndarray:
     return (np.arange(n) + 0.5) / n
+
+
+def spread_order(n: int, base: int) -> np.ndarray:
+    """0..n-1 ranked by the radical inverse of 0, 1, ... in `base` (van
+    der Corput): every run of consecutive entries spreads over 0..n-1."""
+    def inverse(i):
+        x, f = 0.0, 1.0 / base
+        while i:
+            i, d = divmod(i, base)
+            x += d * f
+            f /= base
+        return x
+    return np.argsort(np.argsort([inverse(i) for i in range(n)]))
 
 
 def lengths(spec: dict, n: int) -> np.ndarray:
@@ -106,7 +124,8 @@ def schedule(mix: dict, seed: int, seconds: float, vocab: int) -> list[Request]:
     """The run's requests in due order."""
     rng = np.random.default_rng(seed)
     arr = mix["arrival"]
-    if arr["process"] == "backlog":
+    backlog = arr["process"] == "backlog"
+    if backlog:
         parts = [(np.zeros(int(arr["count"])), int(arr["count"]))]
     elif arr["process"] == "gamma":
         parts = []
@@ -118,8 +137,12 @@ def schedule(mix: dict, seed: int, seconds: float, vocab: int) -> list[Request]:
         raise ValueError(f"unknown arrival process {arr['process']!r}")
     out = []
     for due, n in parts:
-        prompt = rng.permutation(lengths(mix["prompt_len"], n))
-        new = rng.permutation(lengths(mix["output_len"], n))
+        if backlog:
+            prompt = lengths(mix["prompt_len"], n)[spread_order(n, 2)]
+            new = lengths(mix["output_len"], n)[spread_order(n, 3)]
+        else:
+            prompt = rng.permutation(lengths(mix["prompt_len"], n))
+            new = rng.permutation(lengths(mix["output_len"], n))
         out += [Request(float(d), rng.integers(0, vocab, int(p), dtype=np.int32),
                         int(o)) for d, p, o in zip(due, prompt, new)]
     return out
