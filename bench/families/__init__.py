@@ -3,9 +3,10 @@
 A configuration names its family under the optional key
 ``"bench_family"`` (``dense`` where the key is absent); the harness loads
 the module by that name (``harness.load_cell``), as it loads metric
-readers and kernel costs.  Nothing outside the module names a family's
-own sizes, so a new architecture adds a module and data files and edits
-none.  A family module defines:
+readers and kernel costs (a test's data directory may hold its own
+``families/<family>.py``, which comes first).  Nothing outside the module
+names a family's own sizes, so a new architecture adds a module and data
+files and edits none.  A family module defines:
 
 ``spec_from_config(cfg) -> spec``
     The benchmark's own description of the configuration file ``cfg``: a

@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import importlib
+import importlib.util
 import json
 import os
 import shutil
@@ -52,7 +53,17 @@ def _load_json(path: Path) -> dict:
     return json.loads(path.read_text())
 
 
-def _load_module(kind: str, name: str):
+def _load_module(kind: str, name: str, data: Path = BENCH):
+    """``bench.<kind>.<name>``; where ``data`` is another directory (a
+    test's), its ``<kind>/<name>.py`` comes first."""
+    local = data / kind / f"{name}.py"
+    if data != BENCH and local.is_file():
+        spec = importlib.util.spec_from_file_location(
+            f"_bench_data.{kind}.{name}", local)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod
+        spec.loader.exec_module(mod)
+        return mod
     if not (BENCH / kind / f"{name}.py").is_file():
         raise FileNotFoundError(f"missing benchmark file {BENCH / kind / name}.py")
     return importlib.import_module(f"bench.{kind}.{name}")
@@ -74,13 +85,17 @@ class Cell:
 
 def load_cell(bench: dict, name: str, data: Path = BENCH) -> Cell:
     """The cell `name` of `bench` (BENCHMARK.json) with every file it
-    names, loaded; a missing file is an error."""
+    names, loaded from `data` (a family module from `data` where it has
+    one, else from the benchmark's own); a missing file is an error.
+    The cell takes each end-to-end metric that lists it or lists no
+    cells, and each per-layer metric that does so and moves one of those:
+    a metric without a list is every cell's, a new cell's too."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     config = _load_json(data / "configs" / f"{w['config']}.json")
-    family = _load_module("families", config.get("bench_family", "dense"))
+    family = _load_module("families", config.get("bench_family", "dense"), data)
     mix = _load_json(data / "traffic" / f"{w['traffic']}.json")
     check = _load_json(data / "checks" / f"{name}.json")
 

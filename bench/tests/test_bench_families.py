@@ -1,10 +1,13 @@
-"""Model families found by name: every module has the interface, a
-configuration without ``bench_family`` is dense, an unknown family is an
-error that names its file, and the dense family counts FLOPs and draws
-weights as it always has."""
+"""Model families found by name: every module has the interface, each
+cell takes the family its configuration names (dense where it names
+none), a new family joins by files alone and gets the shared metrics, an
+unknown family is an error that names its file, and the dense family
+counts FLOPs and draws weights as it always has."""
 
 import hashlib
+import io
 import json
+import shutil
 from pathlib import Path
 
 import jax
@@ -31,9 +34,73 @@ def test_each_family_has_the_interface(name):
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_a_config_without_a_family_is_dense(cell):
+    # and a configuration that names one takes that module
     c = harness.load_cell(BENCH, cell)
-    assert "bench_family" not in c.config
-    assert c.family is dense
+    named = c.config.get("bench_family")
+    assert c.family is harness._load_module("families", named or "dense")
+    if named is None:
+        assert c.family is dense
+
+
+STUB = '''"""The dense family under a name of its own, as a new family comes."""
+from bench.families.dense import (make_weights, program_config,  # noqa: F401
+                                  program_params, score, spec_from_config)
+'''
+
+
+@pytest.fixture
+def relabelled(tmp_path):
+    """BENCHMARK.json with one more cell, a tiny configuration of the
+    family ``relabelled``; that family, the configuration, its traffic
+    and its check are new files under `tmp_path`, and no file that
+    exists is edited."""
+    for kind in ("configs", "families", "traffic", "checks"):
+        (tmp_path / kind).mkdir()
+    cfg = json.loads((DATA / "configs" / "tiny.json").read_text())
+    cfg.update(name="tiny-relabelled", bench_family="relabelled")
+    (tmp_path / "configs" / "tiny-relabelled.json").write_text(json.dumps(cfg))
+    (tmp_path / "families" / "relabelled.py").write_text(STUB)
+    shutil.copy(DATA / "traffic" / "tiny.json", tmp_path / "traffic")
+    shutil.copy(DATA / "checks" / "tiny.chat.json",
+                tmp_path / "checks" / "tiny-relabelled.chat.json")
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"].append({"name": "tiny-relabelled.chat",
+                               "config": "tiny-relabelled", "traffic": "tiny",
+                               "chips": 1, "why": "a new family's cell"})
+    return bench, tmp_path
+
+
+def test_a_new_family_joins_by_files_alone(relabelled):
+    bench, data = relabelled
+    c = harness.load_cell(bench, "tiny-relabelled.chat", data)
+    assert c.family.__name__.endswith(".relabelled") and c.family is not dense
+    assert c.family.score is dense.score
+    # every metric that lists no cells, the whole step's share among them
+    e2e = {m["name"] for m in bench["end_to_end"] if "workloads" not in m}
+    shared = {m["name"] for m in bench["per_layer"]
+              if "workloads" not in m and m["moves"] in e2e}
+    assert {m["name"] for m in c.end_to_end} == e2e
+    assert {m["name"] for m in c.per_layer} == shared
+    assert {"decode_mfu", "decode_step_ms", "device_idle_share",
+            "qmatmul_roofline", "kv_dequant_roofline", "step_host_ms",
+            "kv_live_read_share"} <= shared
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_a_new_family_is_served_and_checked(relabelled, trace):
+    bench, data = relabelled
+    r = harness.run("tiny-relabelled.chat", 11, 1.5, trace, bench=bench,
+                    data=data, require_chip=False, out=io.StringIO(),
+                    err=io.StringIO())
+    assert r["correct"] and r["attempted"] > 0 and r["failed"] == 0
+    if trace:
+        # the readers of spans read; those of the device trace, on no
+        # chip, read nothing and are left out
+        assert {"decode_step_ms", "step_host_ms",
+                "kv_live_read_share"} <= set(r["metrics"])
+        assert not {"decode_mfu", "qmatmul_roofline"} & set(r["metrics"])
+    else:
+        assert set(r["metrics"]) == {"itl_p50_ms", "setup_s"}
 
 
 def test_an_unknown_family_names_its_file(tmp_path):

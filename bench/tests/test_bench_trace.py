@@ -1,7 +1,10 @@
-"""Trace reduction on a small trace recorded on one TPU v5e (a 2-layer,
+"""Trace reduction on two small traces recorded on one TPU v5e (a 2-layer,
 512-wide qwen2-shaped model, kv4, 4 slots, 3 decode steps each wrapped in
-a ``bench/step`` span), and the kernels' cost functions at the widths of
-both configurations, against counts made by hand."""
+a ``bench/step`` span): one from before the program named its Pallas
+calls, matched by operand signature, and one whose calls carry their
+kernel's name (``data/record_decode_trace.py``), matched by name; and the
+kernels' cost functions at the widths of both configurations, against
+counts made by hand."""
 
 import gzip
 import importlib
@@ -123,3 +126,50 @@ def test_kv_dequant_cost_by_hand(R, feat):
     assert flops == 0
     # 4 bits a feature packed, a bf16 scale per 64, bf16 out
     assert b == R * feat // 2 + R * feat // 64 * 2 + 64 + R * feat * 2
+
+
+@pytest.fixture(scope="module")
+def named():
+    from jax.profiler import ProfileData
+
+    raw = gzip.open(DATA / "decode_named.xplane.pb.gz").read()
+    return ProfileData.from_serialized_xspace(raw)
+
+
+def _ops(pd):
+    return [xspace.parse_op(e.name) for p in pd.planes
+            if p.name.startswith("/device:TPU:") for line in p.lines
+            if line.name == "XLA Ops" for e in line.events]
+
+
+def test_named_calls_are_costed_by_name(named):
+    calls = [op for op in _ops(named) if op["target"] == "tpu_custom_call"]
+    assert {op["kernel"] for op in calls} == set(COSTS)
+    k = xspace.reduce(named, COSTS, PEAKS)["kernels"]
+    # the same steps as the unnamed trace: 3 x (2 layers x 7 matrices +
+    # lm_head) GEMMs, 3 x 2 layers x (K and V) KV reads
+    assert k["_qmatmul_kernel"]["calls"] == 45
+    assert k["_dequant_kernel"]["calls"] == 12
+    # a module that would take every custom call by its operands, first
+    # in name order, takes none of the named ones
+    greedy = {"_a_greedy": type("M", (), {
+        "match": staticmethod(lambda op: {} if op["target"] else None),
+        "cost": staticmethod(lambda sizes: (0.0, 0.0))})}
+    assert xspace.reduce(named, {**greedy, **COSTS}, PEAKS)["kernels"] == k
+
+
+def test_a_named_call_with_no_cost_file_shows_under_its_name(named):
+    r = xspace.reduce(named, {"_qmatmul_kernel": COSTS["_qmatmul_kernel"]}, PEAKS)
+    assert set(r["kernels"]) == {"_qmatmul_kernel"}
+    ops = dict(r["device_ops"])
+    assert ops["_dequant_kernel"] > 0
+    assert not any(n.startswith("closed_call") for n in ops)
+
+
+def test_name_and_signature_matching_agree(named, monkeypatch):
+    by_name = xspace.reduce(named, COSTS, PEAKS)
+    real = xspace.parse_op
+    monkeypatch.setattr(xspace, "parse_op",
+                        lambda name: {**real(name), "kernel": None})
+    by_signature = xspace.reduce(named, COSTS, PEAKS)
+    assert by_name == by_signature

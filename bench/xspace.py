@@ -9,14 +9,19 @@ are lines of ``/host:CPU``; the benchmark's own spans there are named
 ``bench/<what>``.  All event times are nanoseconds from the start of the
 profile, on one clock.
 
-Pallas kernels appear as ``tpu_custom_call`` instructions without their
-kernel's name, so a kernel is recognised by the signature of its operands:
-each ``bench/costs/<kernel>.py`` says which calls are its own and what
-they cost.
+Pallas kernels appear as ``tpu_custom_call`` instructions.  The program
+names each (``pallas_call(..., metadata={"kernel": <name>})``), and the
+instruction's text carries the name as compact JSON in a frontend
+attribute, ``kernel_metadata={"kernel":"_qmatmul_kernel"}`` (newlines
+inside the braces); such a call is costed by ``bench/costs/<name>.py``
+and grouped under its name.  A call whose metadata is empty
+(``kernel_metadata={}``, traces recorded before the names) goes to the
+first cost module whose ``match`` accepts its operands.
 """
 
 from __future__ import annotations
 
+import json
 import re
 
 _SHAPE = re.compile(r"\b(bf16|f16|f32|f64|u8|s8|u16|s16|u32|s32|u64|s64|pred|"
@@ -38,12 +43,22 @@ def nbytes(dtype: str, shape: tuple) -> int:
     return n
 
 
+def _kernel_name(text: str):
+    """The ``"kernel"`` of an instruction's ``kernel_metadata``, or None."""
+    at = text.find("kernel_metadata=")
+    if at < 0:
+        return None
+    meta, _ = json.JSONDecoder().raw_decode(text, at + len("kernel_metadata="))
+    return meta.get("kernel")
+
+
 def parse_op(name: str) -> dict:
     """Split an ``XLA Ops`` event name into the instruction's name, opcode,
-    result shapes, operand shapes and custom-call target."""
+    result shapes, operand shapes, custom-call target and the kernel name
+    its metadata gives (None where it gives none)."""
     head, _, rest = name.partition(" = ")
     op = {"name": head.lstrip("%"), "opcode": None, "out": [], "operands": [],
-          "target": None}
+          "target": None, "kernel": None}
     m = re.search(r"\s([a-z][a-z0-9\-_]*)\(", rest)
     if m is None:
         return op
@@ -54,7 +69,23 @@ def parse_op(name: str) -> dict:
     op["operands"] = _shapes(args)
     t = re.search(r'custom_call_target="([^"]+)"', rest)
     op["target"] = t.group(1) if t else None
+    op["kernel"] = _kernel_name(rest)
     return op
+
+
+def costed(op: dict, costs: dict):
+    """(kernel, sizes) of a call: by the kernel its metadata names, with
+    the sizes that kernel's cost module reads (None where there is no
+    such module, or it cannot read them); else by the first module whose
+    ``match`` accepts the operands; (None, None) for any other op."""
+    if op["kernel"] is not None:
+        mod = costs.get(op["kernel"])
+        return op["kernel"], mod.match(op) if mod is not None else None
+    for kname, mod in costs.items():
+        sizes = mod.match(op)
+        if sizes is not None:
+            return kname, sizes
+    return None, None
 
 
 def op_family(op: dict) -> str:
@@ -97,8 +128,11 @@ def reduce(pd, costs: dict, peaks: dict) -> dict:
     ``bench/`` span (the whole profile where there are none).
 
     ``pd``: ``jax.profiler.ProfileData``; ``costs``: kernel name -> module
-    with ``match(op)`` and ``cost(shapes)``; ``peaks``: the device's row of
-    the peaks table.  Busy and idle are averaged over the chips traced."""
+    with ``match(op)`` and ``cost(sizes)``; ``peaks``: the device's row of
+    the peaks table.  A kernel call is found by ``costed``; a named call
+    that no module costs is left out of ``kernels`` and grouped under its
+    name in ``device_ops``.  Busy and idle are averaged over the chips
+    traced."""
     window_ns = None
     for plane in pd.planes:
         if plane.name == "Task Environment":
@@ -140,12 +174,9 @@ def reduce(pd, costs: dict, peaks: dict) -> dict:
         spans = [(s, e) for _, s, e in ops]
         for (name, s, e), st in zip(ops, _self_times(spans)):
             op = parse_op(name)
-            fam = op_family(op)
-            for kname, mod in costs.items():
-                shapes = mod.match(op)
-                if shapes is None:
-                    continue
-                flops, nb = mod.cost(shapes)
+            kname, sizes = costed(op, costs)
+            if sizes is not None:
+                flops, nb = costs[kname].cost(sizes)
                 k = kernels.setdefault(kname, {"time_s": 0.0, "least_s": 0.0,
                                                "calls": 0, "flops": 0.0,
                                                "bytes": 0.0})
@@ -155,9 +186,8 @@ def reduce(pd, costs: dict, peaks: dict) -> dict:
                 k["calls"] += 1
                 k["flops"] += flops
                 k["bytes"] += nb
-                fam = kname
-                break
-            self_by[fam] = self_by.get(fam, 0.0) + st * 1e-9
+            group = kname or op_family(op)
+            self_by[group] = self_by.get(group, 0.0) + st * 1e-9
         busy = _union((max(a, lo), min(b, hi)) for a, b in spans
                       if b > lo and a < hi)
         busy_ns += sum(b - a for a, b in busy)
